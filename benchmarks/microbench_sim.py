@@ -1097,7 +1097,7 @@ def bench_shard_scaling(n_requests, seeds):
 
     Per (n_channels, engine) cell: mean ± 95% CI of wall seconds and
     events/sec over the seeds, plus per-seed bit-parity (full SimStats
-    dataclass equality between the engines) and whether the Pallas fast
+    dataclass equality between the engines) and whether the lockstep fast
     path actually ran (``fast_path_events`` counter).  ``rel_throughput``
     normalizes every cell against this run's 8-channel array cell, so
     the scaling shape is machine-free; absolute walls are host-dependent

@@ -79,6 +79,9 @@ def _char_cache_path(kind: str, ext: str, **kw) -> Optional[str]:
     d = _char_cache_dir()
     if d is None:
         return None
+    # The backend is part of the key: tables characterized on one
+    # platform never serve a run on another.
+    kw["backend"] = jax.default_backend()
     blob = repr((_CHAR_CACHE_VERSION, kind, sorted(kw.items())))
     h = hashlib.sha1(blob.encode()).hexdigest()[:24]
     return os.path.join(d, f"{kind}_{h}.{ext}")

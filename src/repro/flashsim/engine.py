@@ -132,6 +132,11 @@ class OpBuffers:
     #: die held throughout.
     xa: Optional[List[int]] = None
     xtr: Optional[List[float]] = None
+    #: Channel transfer and decode times (us), on the simulated-time
+    #: tick grid like every other time above (set where the run is
+    #: prepared, :func:`repro.flashsim.ssd._put_on_grid`).
+    tdma: float = 0.0
+    tecc: float = 0.0
 
 
 @dataclasses.dataclass
@@ -148,7 +153,7 @@ class EngineResult:
     online_attempts: int      # online mode: total host-read attempts
     online_read_pages: int    # online mode: host read pages admitted
     #: Events retired by the batched lockstep kernel (0 for interpreter
-    #: runs) — the "Pallas fast path actually ran" observability counter.
+    #: runs) — the "lockstep fast path actually ran" observability counter.
     fast_path_events: int = 0
     #: Number of sweep cells sharing the kernel dispatch that produced
     #: this result (0 = not a fused dispatch) — the "fused sweep
@@ -243,8 +248,7 @@ def _run_shard(
     allocated full-size either way — a shard writes only its owned
     entries, which is what :func:`merge_shard_results` reads back out.
     """
-    t = cfg.timing
-    tdma, tecc = t.tdma_us, t.tecc_us
+    tdma, tecc = bufs.tdma, bufs.tecc
 
     adm_t = bufs.arrival
     op_rid, op_die, op_ch = bufs.rid, bufs.die, bufs.ch
@@ -814,8 +818,7 @@ def run_closed_loop(
         raise NotImplementedError(
             "closed-loop frontend does not support the preempt scheduler"
         )
-    t = cfg.timing
-    tdma, tecc = t.tdma_us, t.tecc_us
+    tdma, tecc = bufs.tdma, bufs.tecc
     hit_us = cache.cfg.hit_us if cache is not None else 0.0
 
     op_rid, op_die, op_ch = bufs.rid, bufs.die, bufs.ch

@@ -234,7 +234,6 @@ def run_event_core_batched(
     """
     check_batched_supported(policy, bufs, online, validate)
 
-    t = cfg.timing
     tables, lane_idx, rid = _lane_tables(cfg, bufs)
 
     from repro.kernels.fcfs_core import fcfs_core
@@ -244,7 +243,7 @@ def run_event_core_batched(
     ops = pad_ops(tables)
     n_dies_local = -(-cfg.n_dies // cfg.n_channels)
     fin, diestat, lane = fcfs_core(
-        ops, n_dies_local, pipelined, t.tdma_us, t.tecc_us,
+        ops, n_dies_local, pipelined, bufs.tdma, bufs.tecc,
         age_bound=bound if mode == "prio" else None)
     return _assemble_result(cfg, rid, lane_idx, fin, diestat, lane,
                             n_requests)
@@ -378,7 +377,7 @@ def run_event_cores_fused(runs) -> list:
                 r, _, _, _, _, bound, _ = prepped[i]
                 b = bound if mode == "prio" else 0.0
                 timing_rows.append(np.tile(
-                    [[r.cfg.timing.tdma_us, r.cfg.timing.tecc_us, b]],
+                    [[r.bufs.tdma, r.bufs.tecc, b]],
                     (n_ch, 1)))
             stacked = np.concatenate(cell_ops, axis=0)
             timing = np.concatenate(timing_rows,

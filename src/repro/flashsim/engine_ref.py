@@ -34,6 +34,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.flashsim.simtime import on_grid
 from repro.flashsim.ssd import PAGE_TYPE_ORDER, SSDSim, SimStats, TraceExpansion
 from repro.flashsim.workloads import RequestTrace
 
@@ -80,9 +81,13 @@ class SSDSimRef(SSDSim):
                 f"scheduler={self.cfg.scheduler!r} with engine='array'"
             )
         cfg, t = self.cfg, self.cfg.timing
-        tdma, tecc, tprog = t.tdma_us, t.tecc_us, t.tprog_us
+        # The same simulated-time tick grid the array engine's inputs
+        # are rounded onto (repro.flashsim.simtime).
+        tdma, tecc, tprog = (on_grid(float(v))
+                             for v in (t.tdma_us, t.tecc_us, t.tprog_us))
+        arrival = on_grid(trace.arrival_us)
         pipelined = self.policy.pipelined
-        tr_by_type = (
+        tr_by_type = on_grid(
             np.array([t.tr_us[pt] for pt in PAGE_TYPE_ORDER]) * self.tr_scale
         )
 
@@ -253,7 +258,7 @@ class SSDSimRef(SSDSim):
         nonlocal_totals = [0, 0]  # attempts, read pages
 
         for rid in range(n):
-            push(float(trace.arrival_us[rid]), admit, rid)
+            push(float(arrival[rid]), admit, rid)
 
         # ------- main loop ----------------------------------------------------
 
@@ -266,7 +271,7 @@ class SSDSimRef(SSDSim):
 
         total_attempts, total_read_pages = nonlocal_totals
         self.last_req_done_us = req_done_at
-        response = req_done_at - trace.arrival_us + cfg.host_overhead_us
+        response = req_done_at - arrival + cfg.host_overhead_us
         read_resp = response[trace.is_read]
         span = float(req_done_at.max())
         return SimStats(

@@ -26,17 +26,16 @@ harnesses that sweep per-seed cells directly.
 
 Cache reuse across workers
 --------------------------
-Workers are forked when the platform allows (``fork`` start method, the
-Linux default): a forked worker inherits the parent's process-wide
-caches copy-on-write — the content-hash trace cache
+Workers are forked (the ``fork`` start method; platforms without it
+run inline): a forked worker inherits the parent's process-wide caches
+copy-on-write — the content-hash trace cache
 (:func:`repro.flashsim.workloads.cached_trace`) and the in-process
 characterization memos — so :func:`run_cells` pre-warms every
 (condition, mechanism) characterization table in the parent *before*
-creating the pool and no worker ever re-enters JAX.  Under ``spawn``
-(or any cold worker) the on-disk characterization cache
-(``~/.cache/repro_flashsim``, see :mod:`repro.core.characterize`) fills
-the same role at a one-read-per-table cost.  Force a start method with
-``REPRO_SWEEP_START_METHOD``; force inline execution (no pool, e.g. in
+creating the pool and no worker ever enters JAX.  Cells that may run
+the lockstep core (``engine="batched"``/``"auto"`` inside the batched
+matrix) never go to a worker: the accelerator belongs to one process,
+so they run in the caller's.  Force inline execution (no pool, e.g. in
 sandboxes without working semaphores) with ``REPRO_SWEEP_INLINE=1``.
 
 Determinism
@@ -290,8 +289,7 @@ def prewarm_characterization(cells: Iterable[Cell]) -> int:
     """Build every (condition, mechanism) table the cells will touch.
 
     Called in the parent before the pool is created so forked workers
-    inherit warm in-process memos (and never call into JAX themselves);
-    under spawn the work instead lands once in the on-disk cache.
+    inherit warm in-process memos (and never call into JAX themselves).
     Returns the number of distinct tables touched.
     """
     from repro.core.retry import RetryPolicy
@@ -309,136 +307,38 @@ def prewarm_characterization(cells: Iterable[Cell]) -> int:
     return len(seen)
 
 
-def _batched_sigs(cells: Iterable[Cell]):
-    """Distinct batched-kernel signatures the cells will (or may) run.
+def _on_device(cell: Cell) -> bool:
+    """Whether ``cell`` may run the lockstep core on the accelerator.
 
-    A cell contributes when its engine is ``"batched"`` or ``"auto"``
-    *and* its knob-overlaid config resolves inside the batched matrix —
-    the same :func:`~repro.flashsim.engine_batched.resolve_engine` call
-    run() will make (auto cells that fall back contribute nothing;
-    that per-cell gate is what keeps prewarm from compiling variants an
-    ``"auto"`` sweep would never launch).  Signature = (lane count,
-    local die count, pipelined, scheduler lowering mode): exactly the
-    static parts of the kernel's jit key that the cell list determines
-    up front.  Fusion-enabled cells additionally contribute their
-    *fused* lane counts — a batch/compare cell's inner grid dispatches
-    at ``min(C, cap) * n_channels`` lanes per pipelined class (``cap``
-    = the engine's fused cell cap), and fusable simulate cells sharing
-    a (workload, n_requests, config) proxy key are counted as one
-    cross-cell chunk — so the widened kernel variants are warmed too,
-    not just the per-cell ones.  (Step-heterogeneous grids may chunk
-    smaller at dispatch time; those narrower variants compile on first
-    use and land in the same persistent cache.)
+    True when its engine is ``"batched"`` or ``"auto"`` and its
+    knob-overlaid config resolves inside the batched matrix — the same
+    :func:`~repro.flashsim.engine_batched.resolve_engine` call run()
+    makes.  The accelerator belongs to one process, so such cells run in
+    the process that holds it, never in a pool worker.
     """
-    from repro.core.retry import RetryPolicy
-    from repro.flashsim.engine_batched import (_fuse_cell_cap,
-                                               resolve_engine)
-    from repro.flashsim.sched import get_scheduler
+    from repro.flashsim.engine_batched import resolve_engine
     from repro.flashsim.ssd import _with_knobs
 
-    sigs = set()
-    cross: Dict[Tuple, Tuple[int, int, int]] = {}
-    for cell in cells:
-        engine = cell.engine if cell.engine is not None else cell.cfg.engine
-        if engine not in ("batched", "auto"):
-            continue
-        cfg = _with_knobs(cell.cfg, cell.scheduler, cell.gc, cell.faults,
-                          cell.ncq_depth, cell.host_cache)
-        if resolve_engine(cfg)[0] != "batched":
-            continue
-        mode, _ = get_scheduler(cfg.scheduler).ring_lowering
-        n_ch = cfg.n_channels
-        n_dies_local = -(-cfg.n_dies // n_ch)
-        for mech in cell.mechanisms:
-            sigs.add((n_ch, n_dies_local, RetryPolicy(mech).pipelined, mode))
-        if not (cfg.fuse if cell.fuse is None else cell.fuse):
-            continue
-        if cell.kind in ("batch", "compare"):
-            # Inner-grid fusion: one dispatch per pipelined class, cell
-            # axis = conditions x same-class mechanisms, pow2-bucketed.
-            for pipe in (False, True):
-                n_mech = sum(1 for m in cell.mechanisms
-                             if RetryPolicy(m).pipelined == pipe)
-                grid = len(cell.conditions) * n_mech
-                if grid > 1:
-                    grid = min(grid, _fuse_cell_cap(n_ch))
-                    sigs.add((grid * n_ch, n_dies_local, pipe, mode))
-        else:
-            # Cross-cell fusion stacks simulate cells whenever their
-            # static kernel shapes and step bounds line up; the
-            # (workload, n_requests, config) proxy (seed-blind — same
-            # workload at different seeds has near-identical step
-            # bounds, so those cells land in one chunk) avoids
-            # resolving traces here.
-            pipe = RetryPolicy(cell.mechanisms[0]).pipelined
-            key = (repr(cell.workload), cell.n_requests,
-                   repr(cfg), pipe, mode)
-            count, _, _ = cross.get(key, (0, 0, 0))
-            cross[key] = (count + 1, n_ch, n_dies_local)
-    for (_, _, _, pipe, mode), (count, n_ch, n_dl) in cross.items():
-        if count > 1:
-            count = min(count, _fuse_cell_cap(n_ch))
-            sigs.add((count * n_ch, n_dl, pipe, mode))
-    return sigs
+    engine = cell.engine if cell.engine is not None else cell.cfg.engine
+    if engine not in ("batched", "auto"):
+        return False
+    cfg = _with_knobs(cell.cfg, cell.scheduler, cell.gc, cell.faults,
+                      cell.ncq_depth, cell.host_cache)
+    return resolve_engine(cfg)[0] == "batched"
 
 
-def prewarm_batched(cells: Iterable[Cell]) -> int:
-    """Compile the batched core's kernel variants before the pool starts.
+def _fork_context():
+    """The ``fork`` start method, or ``None`` where the platform lacks it.
 
-    For every distinct signature in :func:`_batched_sigs`, runs the
-    lockstep kernel once on a tiny synthetic op table in the parent
-    process.  The payoff is the *persistent* compilation cache
-    (:mod:`repro.kernels.fcfs_core.ops`): the parent's compile lands on
-    disk, so every (spawned) worker's first batched cell is a cache hit
-    instead of an XLA compile.  Timing constants, step counts, and
-    aging bounds are traced (not compile keys), so the tiny table warms
-    the same executable a real floor-bucket cell uses; larger shape
-    buckets still compile on first use but land in the same on-disk
-    cache for every later process.  Fused signatures warm through the
-    same :func:`~repro.kernels.fcfs_core.ops._dispatch` path, so a
-    ``C * n_channels``-lane warm run hits the exact jit key a fused
-    chunk with equal statics will ask for (including the ``wide``
-    scatter lowering above 8 lanes).  Returns the number of kernel
-    variants warmed.
+    Pool workers run interpreter (array-engine) cells only and never
+    enter JAX: they read the characterization memos the parent warmed
+    (:func:`prewarm_characterization`) through copy-on-write memory.  A
+    ``spawn`` worker would start cold and characterize in JAX itself,
+    so there is no spawn pool.
     """
-    sigs = _batched_sigs(cells)
-    if not sigs:
-        return 0
-    import numpy as np
-
-    from repro.kernels.fcfs_core import fcfs_core
-    from repro.kernels.fcfs_core.ops import pad_ops
-
-    for n_lanes, n_dies_local, pipelined, mode in sigs:
-        # One host read per lane: [arrival kind die dur attempts tr hp].
-        lane = np.array([[0.0, 0.0, 0.0, 0.0, 1.0, 40.0, 1.0]])
-        fcfs_core(pad_ops([lane] * n_lanes), n_dies_local, pipelined,
-                  100.0, 10.0,
-                  age_bound=16.0 if mode == "prio" else None)
-    return len(sigs)
-
-
-def _mp_context(use_jax: bool = False):
-    """Pool start-method: fork by default, spawn for JAX-using workers.
-
-    Forked children of a JAX-initialized parent deadlock the moment
-    they call back into XLA (the runtime's thread pool does not survive
-    ``os.fork``) — array-engine sweeps never do (workers only read the
-    parent's memoized characterization tables), but batched cells run
-    the kernel *in* the worker, so any sweep whose cells may select the
-    batched engine takes a ``spawn`` pool instead.  Spawned workers pay
-    a fresh interpreter + import, and their kernel compiles are
-    persistent-cache hits thanks to :func:`prewarm_batched`.
-    ``REPRO_SWEEP_START_METHOD`` still overrides both defaults.
-    """
-    method = os.environ.get("REPRO_SWEEP_START_METHOD")
-    if not method:
-        methods = multiprocessing.get_all_start_methods()
-        if use_jax:
-            method = "spawn" if "spawn" in methods else None
-        else:
-            method = "fork" if "fork" in methods else None
-    return multiprocessing.get_context(method)
+    if "fork" not in multiprocessing.get_all_start_methods():
+        return None
+    return multiprocessing.get_context("fork")
 
 
 def _inline_forced() -> bool:
@@ -599,8 +499,10 @@ def run_cells(cells: Sequence[Cell], workers: int = 1,
     """Execute ``cells``; results are returned in input order.
 
     ``workers <= 1`` runs inline (no pool, no pickling — the exact
-    ``workers=1`` code path).  Larger counts fan cells out over a
-    process pool in *chunks* of several cells per task (amortizing the
+    ``workers=1`` code path).  Cells that may run the lockstep core
+    always run inline, in this process (:func:`_on_device`).  Larger
+    counts fan the remaining interpreter cells out over a fork pool in
+    *chunks* of several cells per task (amortizing the
     per-task pickle/IPC overhead that made small-cell sweeps slower
     than inline); results are still assembled positionally, so the
     output is independent of completion order, worker count, and
@@ -637,24 +539,27 @@ def run_cells(cells: Sequence[Cell], workers: int = 1,
     if not pending:
         return results
     workers = min(int(workers), len(pending))
-    if workers <= 1 or _inline_forced():
+    ctx = _fork_context()
+    if workers <= 1 or _inline_forced() or ctx is None:
         return _finish_inline(results, pending, jr)
-    # Cells that may run the batched engine execute JAX *in* the
-    # worker: they need a spawn pool (fork would inherit a broken XLA
-    # runtime — see _mp_context) and, with prewarm, a populated
-    # persistent compile cache so each spawned worker's kernels are
-    # disk hits rather than fresh XLA compiles.
-    use_jax = bool(_batched_sigs(pending.values()))
+    # Cells that may run the lockstep core stay in this process (the
+    # accelerator belongs to one process); only interpreter cells fan
+    # out, over a fork pool whose workers never enter JAX.
+    device = {i: c for i, c in pending.items() if _on_device(c)}
+    if device:
+        _finish_inline(results, device, jr)
+        pending = {i: c for i, c in pending.items() if i not in device}
+    workers = min(workers, len(pending))
+    if workers <= 1:
+        return _finish_inline(results, pending, jr)
     if prewarm:
         prewarm_characterization(pending.values())
-        if use_jax:
-            prewarm_batched(pending.values())
     attempt = 0
     while True:
         try:
             pool = ProcessPoolExecutor(
                 max_workers=min(workers, len(pending)),
-                mp_context=_mp_context(use_jax),
+                mp_context=ctx,
             )
         except (OSError, PermissionError):
             # Sandboxed semaphores / fork unavailable: no pool at all.
@@ -800,22 +705,21 @@ def run_compare(
     Requires the ``fork`` start method (shared views are inherited, not
     pickled); otherwise — or on pool failure — falls back to the inline
     run API.  Results match ``compare_mechanisms(..., workers=1)``
-    exactly, in the caller's mechanism order.  Supports the ``array``
-    and ``batched`` engines (both consume the shared expansion/schedule
-    views).  A fusable batched compare (``fuse=``, default
-    ``cfg.fuse``) skips the pool entirely — one fused dispatch in-process
-    beats per-mechanism fork workers, and the results are bit-identical.
+    exactly, in the caller's mechanism order.  Only interpreter
+    (``array``) compares fork: a compare that may run the lockstep core
+    (:func:`_on_device`) runs inline, in the process that holds the
+    accelerator, fused or not.
     """
     global _COMPARE_PAYLOAD
     from repro.flashsim import ssd
 
     mechanisms = tuple(mechanisms)
-    ctx = _mp_context()
-    fused = ssd._fuse_resolved(
-        ssd._with_knobs(cfg, scheduler, gc), engine, fuse
-    ) and len(mechanisms) > 1
-    if (fused or workers <= 1 or len(mechanisms) <= 1 or _inline_forced()
-            or ctx.get_start_method() != "fork"):
+    ctx = _fork_context()
+    on_device = _on_device(Cell("compare", workload, (condition,),
+                                mechanisms, seed, cfg, engine=engine,
+                                scheduler=scheduler, gc=gc))
+    if (on_device or workers <= 1 or len(mechanisms) <= 1
+            or _inline_forced() or ctx is None):
         return ssd.compare_mechanisms(
             workload, condition, mechanisms=mechanisms, seed=seed, cfg=cfg,
             n_requests=n_requests, engine=engine, scheduler=scheduler,

@@ -108,6 +108,7 @@ from repro.flashsim.config import (
 )
 from repro.flashsim.engine import make_buffers, run_event_core
 from repro.flashsim.sched import get_scheduler
+from repro.flashsim.simtime import on_grid
 from repro.flashsim.workloads import (
     RequestTrace,
     SyntheticSource,
@@ -253,7 +254,7 @@ class SimStats:
     cache_flush_pages: int = 0        # page programs issued by cache flushes
     cache_stalled_writes: int = 0     # writes that waited on cache capacity
     die_sense_util: float = 0.0       # fraction of span dies spent sensing
-    #: Events retired by the batched lockstep (Pallas) fast path — 0 for
+    #: Events retired by the batched lockstep fast path — 0 for
     #: interpreter runs, ``== n_events`` for ``engine="batched"`` runs.
     #: Observability only: excluded from equality so batched-vs-array
     #: bit-identity asserts compare the simulation outcome, not the
@@ -515,8 +516,10 @@ class SSDSim:
         return a if a > 1 else 1
 
     def _tr_for(self, ptype_idx: int, wear_pec: float) -> float:
-        """Per-attempt sense time at (page type, block wear)."""
-        return float(self._tr_base[ptype_idx]) * self._scale_for(wear_pec)
+        """Per-attempt sense time at (page type, block wear), on the
+        simulated-time tick grid."""
+        return on_grid(float(self._tr_base[ptype_idx])
+                       * self._scale_for(wear_pec))
 
     def _sample_attempts(
         self,
@@ -742,8 +745,10 @@ class SSDSim:
                 bufs.xa, bufs.xtr = plan.xa, plan.xtr
                 op_lpn = plan.lpn
 
+        _put_on_grid(bufs, t)
         return _PreparedRun(
-            trace=trace, validate=validate, pipelined=pipelined,
+            trace=trace, arrival_us=on_grid(trace.arrival_us),
+            validate=validate, pipelined=pipelined,
             sched_policy=sched_policy, closed=closed, batched=batched,
             engine_selected=engine_selected, engine_reason=engine_reason,
             schedule=schedule, online=online, fm=fm, bufs=bufs,
@@ -800,7 +805,7 @@ class SSDSim:
                 cache = WriteCache(cfg.host_cache)
             res = run_closed_loop(
                 cfg, prep.pipelined, prep.sched_policy, bufs, n_requests,
-                trace.arrival_us.tolist(), trace.is_read.tolist(),
+                prep.arrival_us.tolist(), trace.is_read.tolist(),
                 cfg.ncq_depth, op_lpn=prep.op_lpn, cache=cache,
                 validate=validate, trace_phases=trace_phases,
             )
@@ -848,7 +853,7 @@ class SSDSim:
 
         req_done_at = np.asarray(res.req_done)
         self.last_req_done_us = req_done_at
-        response = req_done_at - trace.arrival_us + cfg.host_overhead_us
+        response = req_done_at - prep.arrival_us + cfg.host_overhead_us
         read_resp = response[trace.is_read]
         span = float(req_done_at.max())
         if closed:
@@ -856,7 +861,7 @@ class SSDSim:
             # (flush programs / GC can outlive the last host completion).
             span = max(span, max(res.die_busy), max(res.ch_busy))
             admit_at = np.asarray(res.req_admit)
-            wait = admit_at - trace.arrival_us
+            wait = admit_at - prep.arrival_us
             device = req_done_at - admit_at
             read_dev = device[trace.is_read]
             closed_kw = dict(
@@ -947,6 +952,24 @@ class SSDSim:
         )
 
 
+def _put_on_grid(bufs, timing) -> None:
+    """Round the event core's time inputs onto the simulated-time tick.
+
+    Arrivals, durations, sense times (and fault re-read sense times),
+    tDMA and tECC — the inputs every engine shares — are rounded here,
+    once per run (:mod:`repro.flashsim.simtime`), so the interpreter's
+    f64 arithmetic and the lockstep core's int64 ticks agree exactly.
+    """
+    for name in ("arrival", "dur", "tr", "xtr"):
+        v = getattr(bufs, name)
+        if v is not None:
+            g = on_grid(v)
+            setattr(bufs, name, g if isinstance(v, np.ndarray)
+                    else g.tolist())
+    bufs.tdma = on_grid(float(timing.tdma_us))
+    bufs.tecc = on_grid(float(timing.tecc_us))
+
+
 @dataclasses.dataclass
 class _PreparedRun:
     """Inputs of one engine dispatch, held between :meth:`SSDSim._prepare`
@@ -954,6 +977,7 @@ class _PreparedRun:
     cells into one kernel launch."""
 
     trace: RequestTrace
+    arrival_us: np.ndarray    # the trace's arrivals on the tick grid
     validate: bool
     pipelined: bool
     sched_policy: object
@@ -1202,11 +1226,10 @@ def compare_mechanisms(
     expansion, but GC timing legitimately responds to each mechanism's
     latencies.)  ``shard=True`` selects the per-channel sharded event
     core; ``workers > 1`` fans mechanisms over a process pool
-    (:func:`repro.flashsim.runtime.run_compare` — fork platforms only,
-    results identical to the inline run; the fan-out shares the array
-    expansion/schedule with workers, so it supports the ``array`` and
-    ``batched`` engines — ``engine="reference"`` runs its mechanisms
-    sequentially as before).
+    (:func:`repro.flashsim.runtime.run_compare` — fork platforms and
+    the ``array`` engine only, results identical to the inline run;
+    compares that may run the lockstep core stay in this process, and
+    ``engine="reference"`` runs its mechanisms sequentially as before).
     ``ncq_depth=`` / ``host_cache=`` select the closed-loop frontend for
     every mechanism (see :func:`simulate`).  ``fuse=`` controls the
     fused sweep path (default ``cfg.fuse``): when the config resolves

@@ -1,6 +1,7 @@
-"""Lockstep Pallas kernel for the sched-aware open-loop shard core.
+"""Lockstep core for the sched-aware open-loop shard loop: one jitted
+XLA program, the same on CPU and TPU.
 
-One kernel invocation advances *all* channel shards of a run in lockstep:
+One invocation advances *all* channel shards of a run in lockstep:
 the lane dimension (axis 0 everywhere) is the shard/channel, and each
 ``fori_loop`` step retires exactly one event — an admission, a sense
 completion, a die release, or a write-transfer landing — per active lane.
@@ -8,10 +9,12 @@ The channel busy-until collapse is the sequential max-plus recurrence
 
     done = max(ch_busy, t) + tDMA ;  ch_busy = done
 
-carried as a lane vector across steps, evaluated in event order, which is
-what makes the result bit-identical to the interpreter loop in
-:mod:`repro.flashsim.engine` (no reassociation of float arithmetic — the
-exact add/max sequence of ``_run_shard`` is replayed per lane).
+carried as a lane vector across steps, evaluated in event order.  Times
+are int64 ticks of :mod:`repro.flashsim.simtime`; the interpreter loop in
+:mod:`repro.flashsim.engine` computes the same on-grid values in f64
+exactly, so the result is bit-identical to it on any backend — the
+event order of ``_run_shard`` is replayed per lane, and integer
+arithmetic has no rounding to reproduce.
 
 The interpreter's heap is replaced by a bounded merge that is exact by
 construction for the supported matrix (fcfs / host_prio /
@@ -28,14 +31,14 @@ host_prio_aged, gc in {none, prepass}, no faults, open loop):
 ``seqc``, so heap tie-breaking (push order) is reproduced, not
 approximated.
 
-State layout (all f64; integers are exactly representable):
+State layout (int64, times in ticks; ``NEVER`` is "no event"):
 
   ops   (L, MAXP, 10) — [arrival, kind, die, dur, attempts, tr, hp,
                         gdt, gk0, grem0] per op in admission order;
                         kind 0=read 1=write 2=erase 3=pad (arrival
-                        inf); hp is the scheduling class (1.0 = host
+                        NEVER); hp is the scheduling class (1 = host
                         read, the ``host_read`` table of
-                        :mod:`repro.flashsim.sched`; pads 0.0).
+                        :mod:`repro.flashsim.sched`; pads 0).
                         The g* columns are host-precomputed grant
                         attributes (see :func:`augment_ops`): first
                         event delta (tR for reads, dur otherwise),
@@ -48,9 +51,10 @@ State layout (all f64; integers are exactly representable):
                         busy, nonread] (NC=14, the fifo lowering), plus
                         [qhead2, qtail2, byp] under the prio lowering
                         (NC=17); row D is the masked-write sink.
-  fifo  (L, D+1, CAPQ)— per-die FIFO ring of queued op ids; CAPQ is a
-                        host-computed bound (max ops on one die), so
-                        the ring never overwrites a live entry.  Under
+  fifo  (L, D+1, CAPQ)— per-die FIFO ring of queued op ids (int32);
+                        CAPQ is a host-computed bound (max ops on one
+                        die), so the ring never overwrites a live
+                        entry.  Under
                         the prio lowering the last axis doubles
                         (2*CAPQ): the *host-read* (hi) ring lives in
                         slots [0, CAPQ) and the low class (programs, GC
@@ -102,11 +106,8 @@ identical, and it keeps the scatter the buffer's only carry consumer.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 # ops columns
 (_ARR, _KIND, _DIE, _DUR, _A, _TR, _HP, _GDT, _GK0, _GREM0) = range(10)
@@ -114,30 +115,24 @@ from jax.experimental import pallas as pl
 (_EVT, _EVSEQ, _EVOP, _EVKIND, _HELD, _FREE, _REM, _AACT, _TRACT,
  _QHEAD, _QTAIL, _TOT, _BUSY, _NR, _QHEAD2, _QTAIL2, _BYP) = range(17)
 
-_BIGSEQ = 1e18
+#: "Never" for event times and seqs, and the unbounded aging bound: far
+#: above any reachable tick or count, with headroom to add a duration
+#: without overflow.
+NEVER = 2 ** 62
 
 
-def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
-                 lane_ref, *, n_lanes, n_dies, maxp, capq, capw,
-                 capsteps, pipelined, prio, wide):
-    L, D = n_lanes, n_dies
+def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
+          prio, wide):
+    L, maxp, _ = ops.shape
+    D = n_dies
     lanes = jnp.arange(L)
-    inf = jnp.inf
-    ops = ops_ref[...]
-    steps = steps_ref[0]
-    # tDMA/tECC enter as traced *per-lane vectors*, NOT Python
-    # literals: XLA's algebraic simplifier folds
-    # add(add(x, c1), c2) -> add(x, c1+c2) for literal constants,
-    # which reassociates the sense chain (max(chb, t) + tdma) + tecc
-    # and breaks bit-identity with the interpreter.  Parameters are
-    # opaque to that rewrite.  A lane vector (one row per lane) lets
-    # the fused sweep carry per-cell timing while the broadcast of a
-    # single run stays elementwise-identical to the scalar form.
-    tdma = timing_ref[:, 0]
-    tecc = timing_ref[:, 1]
-    # Aging bound for the prio lowering (traced, +inf = plain
-    # host_prio); unread when prio=False.
-    bound = timing_ref[:, 2]
+    i64 = jnp.int64
+    # Per-lane timing rows: a fused sweep carries each cell's tDMA,
+    # tECC and aging bound (NEVER = plain host_prio; unread when
+    # prio=False) on that cell's lanes.
+    tdma = timing[:, 0]
+    tecc = timing[:, 1]
+    bound = timing[:, 2]
 
     def body(t, carry):
         (state, fifo, acq, log, chb, ch_tot, seqc, n_ev,
@@ -148,26 +143,26 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         evseq = state[:, :D, _EVSEQ]
         aq_row = acq[lanes, aq_head % capw]
         aq_empty = aq_head >= aq_tail
-        aq_t = jnp.where(aq_empty, inf, aq_row[:, 0])
-        aq_sq = jnp.where(aq_empty, _BIGSEQ, aq_row[:, 1])
+        aq_t = jnp.where(aq_empty, NEVER, aq_row[:, 0])
+        aq_sq = jnp.where(aq_empty, NEVER, aq_row[:, 1])
         cand_t = jnp.concatenate([evt, aq_t[:, None]], axis=1)
         cand_s = jnp.concatenate([evseq, aq_sq[:, None]], axis=1)
         tmin = cand_t.min(axis=1)
         is_min = cand_t == tmin[:, None]
-        smin = jnp.where(is_min, cand_s, _BIGSEQ).min(axis=1)
+        smin = jnp.where(is_min, cand_s, NEVER).min(axis=1)
         widx = jnp.argmax(is_min & (cand_s == smin[:, None]), axis=1)
 
         adm_row = ops[lanes, ai]
         adm_t = adm_row[:, _ARR]
-        active = (adm_t < inf) | (tmin < inf)
+        active = (adm_t < NEVER) | (tmin < NEVER)
         take_adm = (adm_t <= tmin) & active
         take_ev = (~take_adm) & active
 
         a_kind = adm_row[:, _KIND]
         a_die = adm_row[:, _DIE].astype(jnp.int32)
-        is_r = take_adm & (a_kind == 0.0)
-        is_w = take_adm & (a_kind == 1.0)
-        is_e = take_adm & (a_kind == 2.0)
+        is_r = take_adm & (a_kind == 0)
+        is_w = take_adm & (a_kind == 1)
+        is_e = take_adm & (a_kind == 2)
 
         ev_acq = take_ev & (widx == D)
         ev_die = take_ev & (widx < D)
@@ -187,11 +182,11 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
             q_empty = hi_empty & lo_empty
         else:
             q_empty = row[:, _QTAIL] == row[:, _QHEAD]
-        die_free = (row[:, _FREE] == 1.0) & q_empty
+        die_free = (row[:, _FREE] == 1) & q_empty
 
         ev_kind = row[:, _EVKIND]
-        ev_sense = ev_die & (ev_kind == 0.0)
-        ev_rel = ev_die & (ev_kind == 1.0)
+        ev_sense = ev_die & (ev_kind == 0)
+        ev_rel = ev_die & (ev_kind == 1)
 
         # -- the channel collapse (write admission DMA or sense DMA;
         #    a step is one or the other, so one max-plus update) --
@@ -207,7 +202,7 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         # computed row indices — both the generic scatter op and a
         # one-hot blend over the ring measured slower.
         aq_slot = jnp.where(is_w, aq_tail % capw, capw)
-        aq_new = jnp.stack([c_done, seqc, ai.astype(jnp.float64),
+        aq_new = jnp.stack([c_done, seqc, ai.astype(i64),
                             adm_row[:, _DIE]], axis=1)
         if wide:
             acq = acq.at[lanes, aq_slot].set(
@@ -223,15 +218,15 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         s_tm = tmin
         s_tr = row[:, _TRACT]
         if not pipelined:
-            s_more = row[:, _REM] > 1.0
-            s_next = jnp.where(s_more, (c_done + tecc) + s_tr, c_done)
-            s_rem = row[:, _REM] - 1.0
+            s_more = row[:, _REM] > 1
+            s_next = jnp.where(s_more, c_done + tecc + s_tr, c_done)
+            s_rem = row[:, _REM] - 1
         else:
-            s_more = row[:, _REM] + 1.0 < row[:, _AACT]
-            s_rel = jnp.where(row[:, _AACT] > 1.0, s_tm + s_tr, s_tm)
+            s_more = row[:, _REM] + 1 < row[:, _AACT]
+            s_rel = jnp.where(row[:, _AACT] > 1, s_tm + s_tr, s_tm)
             s_next = jnp.where(s_more,
                                jnp.maximum(s_tm + s_tr, c_done), s_rel)
-            s_rem = row[:, _REM] + 1.0
+            s_rem = row[:, _REM] + 1
         s_fin = c_done + tecc
 
         # -- grants: admission (free die), ACQ landing, release pop --
@@ -239,8 +234,7 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         g_adm = (is_r | is_e) & die_free
         g_acq = ev_acq & die_free
         queue_push = ((is_r | is_e) & ~die_free) | (ev_acq & ~die_free)
-        push_val = jnp.where(take_adm, ai.astype(jnp.float64),
-                             o_acq.astype(jnp.float64))
+        push_val = jnp.where(take_adm, ai, o_acq)
 
         # FIFO push before the pop gather (see module docstring)
         push_die = jnp.where(queue_push, tgt, D)
@@ -250,7 +244,7 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
             # is the sink for them).  Class picks the ring *region* of
             # the shared buffer: hi at [0, capq), lo at [capq, 2*capq)
             # — one scatter per lane either way.
-            push_hp = ops[lanes, push_val.astype(jnp.int32), _HP] == 1.0
+            push_hp = ops[lanes, push_val, _HP] == 1
             push_hi = queue_push & push_hp
             push_lo = queue_push & ~push_hp
             push_slot = jnp.where(
@@ -285,7 +279,7 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
                 row[:, _QHEAD].astype(jnp.int32) % capq)
         else:
             qh = row[:, _QHEAD].astype(jnp.int32) % capq
-        o2 = fifo[lanes, tgt, qh].astype(jnp.int32)
+        o2 = fifo[lanes, tgt, qh]
 
         # one gather serves every grant source: popped op, admitted op,
         # or the ACQ-landed op (masked lanes read a harmless row)
@@ -299,21 +293,20 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         new_evt = jnp.where(
             ev_sense, s_next,
             jnp.where(grant_any, gr_tm + g_row[:, _GDT],
-                      jnp.where(ev_rel, inf, row[:, _EVT])))
+                      jnp.where(ev_rel, NEVER, row[:, _EVT])))
         sets_ev = ev_sense | grant_any
         new_evseq = jnp.where(sets_ev, seqc, row[:, _EVSEQ])
-        new_evop = jnp.where(grant_any, g_op.astype(jnp.float64),
-                             row[:, _EVOP])
+        new_evop = jnp.where(grant_any, g_op.astype(i64), row[:, _EVOP])
         # kind after this step: sense chains stay 0 until the final
         # attempt converts to a release; grants start at the op's
         # precomputed gk0 (reads 0, writes/erases 1).
         new_evkind = jnp.where(ev_sense,
-                               jnp.where(s_more, 0.0, 1.0),
+                               jnp.where(s_more, 0, 1),
                                jnp.where(grant_any, g_row[:, _GK0],
                                          row[:, _EVKIND]))
         new_held = jnp.where(grant_any, gr_tm, row[:, _HELD])
-        new_free = jnp.where(grant_any, 0.0,
-                             jnp.where(ev_rel & ~q_nonempty, 1.0,
+        new_free = jnp.where(grant_any, 0,
+                             jnp.where(ev_rel & ~q_nonempty, 1,
                                        row[:, _FREE]))
         new_rem = jnp.where(ev_sense, s_rem,
                             jnp.where(grant_any, g_row[:, _GREM0],
@@ -322,19 +315,16 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         new_tract = jnp.where(grant_any, g_row[:, _TR], row[:, _TRACT])
         new_nr = jnp.where(grant_any, g_row[:, _GK0], row[:, _NR])
         if prio:
-            new_qhead = row[:, _QHEAD] + \
-                (grant2 & ~pop_lo).astype(jnp.float64)
-            new_qhead2 = row[:, _QHEAD2] + \
-                (grant2 & pop_lo).astype(jnp.float64)
-            new_qtail = row[:, _QTAIL] + push_hi.astype(jnp.float64)
-            new_qtail2 = row[:, _QTAIL2] + push_lo.astype(jnp.float64)
+            new_qhead = row[:, _QHEAD] + (grant2 & ~pop_lo).astype(i64)
+            new_qhead2 = row[:, _QHEAD2] + (grant2 & pop_lo).astype(i64)
+            new_qtail = row[:, _QTAIL] + push_hi.astype(i64)
+            new_qtail2 = row[:, _QTAIL2] + push_lo.astype(i64)
             new_byp = jnp.where(
-                grant2,
-                jnp.where(pop_lo, 0.0, byp + lo_ne.astype(jnp.float64)),
+                grant2, jnp.where(pop_lo, 0, byp + lo_ne.astype(i64)),
                 byp)
         else:
-            new_qhead = row[:, _QHEAD] + grant2.astype(jnp.float64)
-            new_qtail = row[:, _QTAIL] + queue_push.astype(jnp.float64)
+            new_qhead = row[:, _QHEAD] + grant2.astype(i64)
+            new_qtail = row[:, _QTAIL] + queue_push.astype(i64)
         new_tot = jnp.where(ev_rel, row[:, _TOT] + (r_tm - row[:, _HELD]),
                             row[:, _TOT])
         new_busy = jnp.where(ev_rel, r_tm, row[:, _BUSY])
@@ -344,7 +334,7 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
                 new_qtail, new_tot, new_busy, new_nr]
         if prio:
             cols += [new_qhead2, new_qtail2, new_byp]
-        new_row = jnp.stack(cols, axis=1)
+        new_row = jnp.stack([c.astype(i64) for c in cols], axis=1)
         # Per-lane dynamic_update_slice (static lane, computed die row):
         # measurably cheaper than both XLA:CPU's generic scatter and a
         # one-hot blend at shard-core lane counts, and still updated in
@@ -367,12 +357,10 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         # read in the loop, so one dynamic_update_slice replaces L
         # per-lane writes; the host scatters the log afterwards.
         fin_sense = ev_sense & ~s_more
-        fin_rel = ev_rel & (row[:, _NR] == 1.0)
-        fin_idx = jnp.where(fin_sense | fin_rel,
-                            row[:, _EVOP].astype(jnp.int32), maxp)
+        fin_rel = ev_rel & (row[:, _NR] == 1)
+        fin_idx = jnp.where(fin_sense | fin_rel, row[:, _EVOP], maxp)
         fin_val = jnp.where(fin_sense, s_fin, r_tm)
-        entry = jnp.concatenate(
-            [fin_val, fin_idx.astype(jnp.float64)])[None, :]
+        entry = jnp.concatenate([fin_val, fin_idx])[None, :]
         log = jax.lax.dynamic_update_slice(log, entry,
                                            (t, jnp.int32(0)))
 
@@ -380,73 +368,62 @@ def _core_kernel(ops_ref, steps_ref, timing_ref, log_ref, diestat_ref,
         # grant, and per sense continuation — exactly the interpreter's
         # seqc increments.
         pushed = is_w | grant_any | ev_sense
-        seqc = seqc + pushed.astype(jnp.float64)
-        n_ev = n_ev + take_ev.astype(jnp.float64)
+        seqc = seqc + pushed.astype(i64)
+        n_ev = n_ev + take_ev.astype(i64)
         ai = ai + take_adm.astype(jnp.int32)
 
         return (state, fifo, acq, log, chb, ch_tot, seqc, n_ev,
                 ai, aq_head, aq_tail)
 
-    zero_l = jnp.zeros((L,), jnp.float64)
+    zero_l = jnp.zeros((L,), i64)
     zero_i = jnp.zeros((L,), jnp.int32)
     ncols = 17 if prio else 14
-    state0 = jnp.zeros((L, D + 1, ncols), jnp.float64)
-    state0 = state0.at[:, :, _EVT].set(jnp.inf)
-    state0 = state0.at[:, :, _FREE].set(1.0)
+    state0 = jnp.zeros((L, D + 1, ncols), i64)
+    state0 = state0.at[:, :, _EVT].set(NEVER)
+    state0 = state0.at[:, :, _FREE].set(1)
     # Under the prio lowering the slot axis doubles: hi ring at
     # [0, capq), low ring at [capq, 2*capq) of the same buffer.
-    fifo0 = jnp.zeros((L, D + 1, capq * (2 if prio else 1)),
-                      jnp.float64)
-    acq0 = jnp.zeros((L, capw + 1, 4), jnp.float64)
+    fifo0 = jnp.zeros((L, D + 1, capq * (2 if prio else 1)), jnp.int32)
+    acq0 = jnp.zeros((L, capw + 1, 4), i64)
     # Unwritten log rows (t >= steps) keep op id maxp — the sink slot
     # the host scatter discards.
     log0 = jnp.concatenate(
-        [jnp.zeros((capsteps, L), jnp.float64),
-         jnp.full((capsteps, L), float(maxp), jnp.float64)], axis=1)
+        [jnp.zeros((capsteps, L), i64),
+         jnp.full((capsteps, L), maxp, i64)], axis=1)
 
     carry = (state0, fifo0, acq0, log0, zero_l, zero_l, zero_l,
              zero_l, zero_i, zero_i, zero_i)
     (state, fifo, acq, log, chb, ch_tot, seqc, n_ev,
      ai, aq_head, aq_tail) = jax.lax.fori_loop(0, steps, body, carry)
 
-    log_ref[...] = log
-    diestat_ref[...] = jnp.stack(
-        [state[:, :D, _TOT], state[:, :D, _BUSY]], axis=2)
-    lane_ref[...] = jnp.stack([chb, ch_tot, n_ev, seqc], axis=1)
+    diestat = jnp.stack([state[:, :D, _TOT], state[:, :D, _BUSY]], axis=2)
+    return log, diestat, jnp.stack([chb, ch_tot, n_ev, seqc], axis=1)
 
 
 def fcfs_core_fwd(ops, steps, timing, *, n_dies, capq, capw, capsteps,
-                  pipelined, prio=False, wide=False, interpret=True):
-    """Run the lockstep shard core.
+                  pipelined, prio=False, wide=False):
+    """Run the lockstep shard core (traced under ``jax.enable_x64``).
 
-    ``ops``: (L, MAXP, 10) f64 augmented padded op table (admission
-    order per lane; see :func:`augment_ops`).  ``steps``: (1,) i32 —
-    total lockstep steps (max lane admissions + events; idle lanes
-    no-op).  ``timing``: (L, 3) f64 — per-lane [tdma, tecc, age_bound]
-    rows; a single run broadcasts one row to all lanes, a fused sweep
-    carries each cell's scalars on that cell's lanes.  The bound is
-    traced (+inf = plain host_prio) and unread when ``prio`` is False.
-    ``capq``/``capw`` — static FIFO/ACQ ring capacities (host-computed
-    bounds: max ops on one die / max writes on one lane); ``capsteps``
-    — static log length, a power of two >= steps.  ``prio`` selects
-    the dual-ring scheduler lowering and ``wide`` the batched-scatter
-    carry updates for large fused lane counts (both static: distinct
-    compiled kernels, identical results).
-    Returns ``(log, diestat, lane)``: the per-step completion log
-    (scatter it into the per-op ``fin`` table host-side), per-die
-    [tot, busy], and per-lane [ch_busy, ch_tot, n_events, seqc].
+    ``ops``: (L, MAXP, 10) int64 augmented padded op table, times in
+    ticks (admission order per lane; see :func:`augment_ops`).
+    ``steps``: () int32 — total lockstep steps (max lane admissions +
+    events; idle lanes no-op).  ``timing``: (L, 3) int64 — per-lane
+    [tdma, tecc, age_bound] rows (ticks, ticks, count); a single run
+    broadcasts one row to all lanes, a fused sweep carries each cell's
+    values on that cell's lanes.  The bound is traced (``NEVER`` = plain
+    host_prio) and unread when ``prio`` is False.  ``capq``/``capw`` —
+    static FIFO/ACQ ring capacities (host-computed bounds: max ops on
+    one die / max writes on one lane); ``capsteps`` — static log length,
+    a power of two >= steps.  ``prio`` selects the dual-ring scheduler
+    lowering and ``wide`` the batched-scatter carry updates for large
+    fused lane counts (both static: distinct compiled programs,
+    identical results).  Returns ``(log, diestat, lane)``, all int64:
+    the per-step completion log (scatter it into the per-op ``fin``
+    table host-side), per-die [tot, busy], and per-lane [ch_busy,
+    ch_tot, n_events, seqc].  Runs under the ``fcfs_core`` named scope,
+    which is how a profile finds it.
     """
-    L, maxp, _ = ops.shape
-    kernel = functools.partial(
-        _core_kernel, n_lanes=L, n_dies=n_dies, maxp=maxp, capq=capq,
-        capw=capw, capsteps=capsteps, pipelined=pipelined, prio=prio,
-        wide=wide)
-    return pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((capsteps, 2 * L), jnp.float64),
-            jax.ShapeDtypeStruct((L, n_dies, 2), jnp.float64),
-            jax.ShapeDtypeStruct((L, 4), jnp.float64),
-        ],
-        interpret=interpret,
-    )(ops, steps, timing)
+    with jax.named_scope("fcfs_core"):
+        return _core(ops, steps, timing, n_dies=n_dies, capq=capq,
+                     capw=capw, capsteps=capsteps, pipelined=pipelined,
+                     prio=prio, wide=wide)

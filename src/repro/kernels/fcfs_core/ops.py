@@ -1,90 +1,99 @@
 """Dispatch wrapper for the lockstep sched-aware shard core.
 
-``fcfs_core`` takes the padded per-lane op table as numpy, runs the
-Pallas kernel (natively on TPU, under ``interpret=True`` on CPU — which
-lowers the identical loop to XLA in f64), and returns numpy results.
-All jax work happens inside a scoped ``enable_x64`` context so the f64
-requirement never leaks into the process-global jax config (other
-kernels in this repo compile under the default f32).
+``fcfs_core`` takes the padded per-lane op table as numpy (µs, f64),
+converts its times to int64 ticks of :mod:`repro.flashsim.simtime` —
+raising on a time off the tick grid, never rounding — runs the core as
+one jitted XLA program, the same on CPU and TPU, and returns numpy
+results in µs.  There is no interpret mode and no backend switch: the
+program runs on JAX's default device, and fails there rather than
+falling back.  All JAX work happens inside a scoped ``jax.enable_x64``
+context so the int64 requirement never leaks into the process-global
+JAX config (the characterization compiles under the default 32 bits).
+
+Integer ticks are what make the result exact on every backend: the
+interpreter's f64 add/max on on-grid values is exact, and so is the
+core's int64 arithmetic.  The TPU emulates f64 with pairs of f32, which
+is not IEEE f64; its emulated s64 add/max/compare are exact.
 
 Compiled-variant reuse (the dispatch-overhead contract)
 -------------------------------------------------------
-The kernel is jit-cached per (lane count, padded width, die count,
-ring capacities, pipelined flag, scheduler lowering); the step count,
-timing constants, and aging bound are *traced* scalars, so different
-workload sizes, timing models, and ``host_prio_aged`` bounds all reuse
-one executable.  Every static shape is bucketed to a power of two with
-a small floor (``pad_ops``, ``ring_caps``, ``capsteps``), so a sweep
-grid's cells collapse onto a handful of compiled variants.  On top of
-the in-process jit cache, the first call points JAX's *persistent*
-compilation cache at the repo's standard on-disk cache directory
-(``~/.cache/repro_flashsim`` — same ``REPRO_CHAR_CACHE`` /
-``REPRO_CHAR_CACHE_DIR`` conventions as the characterization cache in
-:mod:`repro.core.characterize`), so fresh processes — spawned sweep
-workers, CI lanes, repeated benchmark runs — skip XLA compilation
-entirely after the first run on a machine.
+The core is jit-cached per (lane count, padded width, die count, ring
+capacities, pipelined flag, scheduler lowering); the step count, timing
+constants, and aging bound are *traced*, so different workload sizes,
+timing models, and ``host_prio_aged`` bounds all reuse one executable.
+Every static shape is bucketed to a power of two with a small floor
+(``pad_ops``, ``ring_caps``, ``capsteps``), so a sweep grid's cells
+collapse onto a handful of compiled variants.  On top of the in-process
+jit cache, the first dispatch turns on JAX's *persistent* compilation
+cache at :func:`compile_cache_dir`, so a fresh process of the same
+checkout skips XLA compilation after the first run.
 """
 
 from __future__ import annotations
 
-import functools
 import os
+import pathlib
 from typing import Optional
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
-from repro.kernels.fcfs_core.kernel import fcfs_core_fwd
+from repro.flashsim.simtime import from_ticks, to_ticks
+from repro.kernels.fcfs_core.kernel import (_ARR, _DUR, _GDT, _KIND, _TR,
+                                            NEVER, fcfs_core_fwd)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
+#: The checkout this module runs from (``src/repro/kernels/fcfs_core``
+#: is four levels below it).
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[4]
 
 _COMP_CACHE_READY = False
 
 
-def _enable_persistent_cache() -> None:
-    """Point JAX's compilation cache at ``~/.cache/repro_flashsim``.
+def compile_cache_dir() -> Optional[str]:
+    """Directory of JAX's persistent compilation cache.
 
-    Best-effort and idempotent: respects ``REPRO_CHAR_CACHE=0`` (fully
-    disabled) and ``REPRO_CHAR_CACHE_DIR`` (relocated), and never fails
-    the computation — an unwritable cache dir just means cold compiles.
-    The thresholds are zeroed because the kernels here are small but
-    re-traced in every fresh worker process; default thresholds would
-    skip exactly the entries we want persisted.
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it itself);
+    otherwise the fixed ``.jax_cache`` directory of the checkout — a
+    stable path, so every process of this checkout hits the same
+    entries.  ``None`` when the package does not run from a checkout
+    (no ``pyproject.toml`` beside ``src/``): no persistent cache then.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if (_CHECKOUT / "pyproject.toml").is_file():
+        return str(_CHECKOUT / ".jax_cache")
+    return None
+
+
+def _enable_persistent_cache() -> None:
+    """Turn on JAX's persistent compilation cache at
+    :func:`compile_cache_dir` (idempotent).
+
+    Sets the directory only when ``JAX_COMPILATION_CACHE_DIR`` is unset.
+    The size and compile-time thresholds are zeroed so every lockstep
+    variant is persisted, however fast it compiled.
     """
     global _COMP_CACHE_READY
     if _COMP_CACHE_READY:
         return
     _COMP_CACHE_READY = True
-    if os.environ.get("REPRO_CHAR_CACHE", "1") == "0":
+    d = compile_cache_dir()
+    if d is None:
         return
-    d = os.environ.get("REPRO_CHAR_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "repro_flashsim"
-    )
-    try:
-        os.makedirs(d, exist_ok=True)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass  # cache is best-effort; never fail the computation
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 
 
-@functools.partial(
-    jax.jit,
+_core_jit = jax.jit(
+    fcfs_core_fwd,
     static_argnames=("n_dies", "capq", "capw", "capsteps", "pipelined",
-                     "prio", "wide", "interpret"))
-def _core_jit(ops, steps, timing, *, n_dies, capq, capw, capsteps,
-              pipelined, prio, wide, interpret):
-    return fcfs_core_fwd(ops, steps, timing, n_dies=n_dies, capq=capq,
-                         capw=capw, capsteps=capsteps,
-                         pipelined=pipelined, prio=prio, wide=wide,
-                         interpret=interpret)
+                     "prio", "wide"))
 
 
 #: Number of kernel dispatches issued by this process (both the
@@ -212,19 +221,40 @@ def ring_caps(ops: np.ndarray, n_dies: int):
     return _pow2_at_least(max(per_die, 4)), _pow2_at_least(max(writes, 4))
 
 
+def _tick_table(aug: np.ndarray) -> np.ndarray:
+    """The augmented (L, MAXP, 10) f64 op table as int64: time columns in
+    ticks (pad arrivals ``NEVER``), count columns as integers."""
+    real = aug[:, :, _KIND] != 3.0
+    out = np.where(np.isfinite(aug), aug, 0.0).astype(np.int64)
+    out[:, :, _ARR] = NEVER
+    for c in (_ARR, _DUR, _TR, _GDT):
+        out[:, :, c][real] = to_ticks(aug[:, :, c][real])
+    return out
+
+
+def _tick_timing(timing: np.ndarray) -> np.ndarray:
+    """Per-lane [tdma, tecc, age_bound] rows as int64: times in ticks,
+    the bound rounded up to a count (``byp >= 2.5`` is ``byp >= 3``),
+    ``inf`` as ``NEVER``."""
+    out = np.empty(timing.shape, np.int64)
+    out[:, :2] = to_ticks(timing[:, :2])
+    out[:, 2] = np.minimum(np.ceil(timing[:, 2]), NEVER).astype(np.int64)
+    return out
+
+
 def _dispatch(ops: np.ndarray, n_dies: int, pipelined: bool,
               timing: np.ndarray, prio: bool,
               caps=None, steps=None):
-    """One kernel dispatch on a padded table with per-lane timing rows.
+    """One dispatch of the core on a padded table with per-lane timing rows.
 
-    ``timing`` is (L, 3) f64 — per-lane [tdma, tecc, age_bound].
-    ``caps`` optionally forces static ``(capq, capw, capsteps)`` (the
-    fused sweep buckets them group-wide; capacity is semantics-neutral
-    because the rings pair via monotone counters).  ``steps`` skips the
-    :func:`count_steps` recount when the caller already knows the bound
-    (the fused router counts per cell before stacking; the max over a
-    chunk's cells equals the stacked count).  Returns numpy
-    ``(fin, diestat, lane)``.
+    ``timing`` is (L, 3) f64 — per-lane [tdma, tecc, age_bound] (µs, µs,
+    count).  ``caps`` optionally forces static ``(capq, capw,
+    capsteps)`` (the fused sweep buckets them group-wide; capacity is
+    semantics-neutral because the rings pair via monotone counters).
+    ``steps`` skips the :func:`count_steps` recount when the caller
+    already knows the bound (the fused router counts per cell before
+    stacking; the max over a chunk's cells equals the stacked count).
+    Returns numpy ``(fin, diestat, lane)`` in µs (counts as f64).
     """
     global KERNEL_DISPATCHES
     _enable_persistent_cache()
@@ -238,25 +268,26 @@ def _dispatch(ops: np.ndarray, n_dies: int, pipelined: bool,
         if steps > capsteps:
             raise ValueError(f"steps {steps} > capsteps {capsteps}")
     L, maxp = ops.shape[0], ops.shape[1]
-    with enable_x64():
+    table = _tick_table(augment_ops(ops, pipelined))
+    tick_timing = _tick_timing(timing)
+    with jax.enable_x64(True):
         log, diestat, lane = _core_jit(
-            jnp.asarray(augment_ops(ops, pipelined), jnp.float64),
-            jnp.asarray([steps], jnp.int32),
-            jnp.asarray(timing, jnp.float64),
+            jnp.asarray(table), jnp.int32(steps), jnp.asarray(tick_timing),
             n_dies=n_dies, capq=capq, capw=capw, capsteps=capsteps,
-            pipelined=pipelined, prio=prio, wide=L > _WIDE_LANES,
-            interpret=_use_interpret())
-        log = np.asarray(log)
+            pipelined=pipelined, prio=prio, wide=L > _WIDE_LANES)
+        log, diestat, lane = (np.asarray(log), np.asarray(diestat),
+                              np.asarray(lane))
     KERNEL_DISPATCHES += 1
     # Scatter the per-step completion log into the per-op fin table.
     # Each real op id appears at most once; idle rows carry the sink id
     # maxp, zeroed afterwards.  Rows past ``steps`` were never written
     # (all-sink) — skip them.
     fin = np.zeros((L, maxp + 1), dtype=np.float64)
-    fin[np.arange(L)[None, :], log[:steps, L:].astype(np.int64)] = \
-        log[:steps, :L]
+    fin[np.arange(L)[None, :], log[:steps, L:]] = from_ticks(log[:steps, :L])
     fin[:, maxp] = 0.0
-    return (fin, np.asarray(diestat), np.asarray(lane))
+    lane_us = np.concatenate(
+        [from_ticks(lane[:, :2]), lane[:, 2:].astype(np.float64)], axis=1)
+    return fin, from_ticks(diestat), lane_us
 
 
 def fcfs_core(ops: np.ndarray, n_dies: int, pipelined: bool,
@@ -271,7 +302,9 @@ def fcfs_core(ops: np.ndarray, n_dies: int, pipelined: bool,
     completion contributions (L, MAXP+1), per-die
     [busy_total, last_release] (L, n_dies, 2), and per-lane
     [ch_busy, ch_tot, n_events, seq] (L, 4).  Bit-identical to
-    :func:`fcfs_core_ref` on CPU.
+    :func:`fcfs_core_ref` on any backend; every time in ``ops`` and
+    ``tdma``/``tecc`` must lie on the tick grid
+    (:func:`repro.flashsim.simtime.on_grid`), else ``ValueError``.
     """
     prio = age_bound is not None
     bound = float(age_bound) if prio else 0.0

@@ -1,12 +1,13 @@
 """Plain-Python reference for the lockstep sched-aware shard core.
 
-Implements the same bounded-stream-merge algorithm as the Pallas kernel
+Implements the same bounded-stream-merge algorithm as the lockstep core
 (:mod:`repro.kernels.fcfs_core.kernel`) — per-die single event slot,
 write-transfer FIFO, admission cursor, explicit seq counters — one lane
-at a time, with the identical float arithmetic (Python floats are IEEE
-f64, and every add/max is written in the interpreter's association
-order).  Used by the parity tests to pin the kernel bit-for-bit, and as
-the unbatched fallback oracle.
+at a time, in Python floats (IEEE f64, every add/max in the
+interpreter's association order).  On tables whose times lie on the
+tick grid of :mod:`repro.flashsim.simtime` that arithmetic is exact, as
+is the core's int64; the parity tests pin the core to this oracle
+bit-for-bit.
 
 ``age_bound`` selects the scheduler: ``None`` is the single FIFO ring;
 a float bound (``inf`` = plain host_prio) runs the dual priority rings

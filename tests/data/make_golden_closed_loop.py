@@ -9,7 +9,9 @@ code landed must be byte-identical.
 Run from the repo root (only when the open-loop contract legitimately
 changes, which should essentially never happen):
 
-    PYTHONPATH=src python tests/data/make_golden_closed_loop.py
+    PYTHONPATH=src JAX_PLATFORMS=cpu python tests/data/make_golden_closed_loop.py
+
+It prints how many pinned values moved and the largest relative change.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ import dataclasses
 import json
 import pathlib
 
+from make_golden_workloads import FIELDS, moved
 from repro.flashsim import FaultConfig, OperatingCondition, simulate
 
 OUT = pathlib.Path(__file__).resolve().parent / "golden_closed_loop.json"
@@ -36,6 +39,20 @@ FAULTS = {
 }
 
 
+#: The pinned SimStats fields: the plain and GC block plus the fault
+#: block (the closed-loop block is asserted zero by the test instead).
+PINNED = FIELDS + (
+    "mispredicted_reads", "rescued_reads", "parity_rebuilds",
+    "rebuild_reads", "retired_blocks", "program_fails", "erase_fails",
+    "unrecoverable", "recovery_p99_us",
+)
+
+
+def pinned(stats) -> dict:
+    d = dataclasses.asdict(stats)
+    return {f: d[f] for f in PINNED}
+
+
 def cell_key(mech: str, sched: str, gc: str, faults: str) -> str:
     return f"{mech}|{sched}|{gc}|{faults}"
 
@@ -49,9 +66,7 @@ def main() -> None:
                     "prn", COND, "pr2ar2", seed=SEED, n_requests=N,
                     scheduler=sched, gc=gc, faults=fc,
                 )
-                cells[cell_key("pr2ar2", sched, gc, fname)] = (
-                    dataclasses.asdict(stats)
-                )
+                cells[cell_key("pr2ar2", sched, gc, fname)] = pinned(stats)
     # A couple of baseline-mechanism / read-heavy cells so the pin is not
     # pr2ar2-only.
     for mech in ("baseline", "sota+pr2ar2"):
@@ -59,7 +74,7 @@ def main() -> None:
             "websearch", COND, mech, seed=SEED, n_requests=N,
             scheduler="fcfs", gc="off",
         )
-        cells[cell_key(mech, "fcfs", "off", "none")] = dataclasses.asdict(stats)
+        cells[cell_key(mech, "fcfs", "off", "none")] = pinned(stats)
 
     payload = {
         "meta": {
@@ -79,6 +94,9 @@ def main() -> None:
         },
         "cells": cells,
     }
+    if OUT.exists():
+        n, worst = moved(json.loads(OUT.read_text()), payload)
+        print(f"{n} pinned values moved; largest relative change {worst!r}")
     OUT.write_text(json.dumps(payload, indent=1, sort_keys=True) + "\n")
     print(f"wrote {OUT} ({len(cells)} cells)")
 

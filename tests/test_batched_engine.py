@@ -34,6 +34,7 @@ from repro.flashsim.config import (
 )
 from repro.flashsim.engine_batched import BatchedUnsupported
 from repro.flashsim.sched import SCHEDULERS
+from repro.flashsim.simtime import on_grid
 from repro.flashsim.ssd import (
     compare_mechanisms,
     simulate,
@@ -209,13 +210,13 @@ class TestKernelVsReference:
 
     @staticmethod
     def _random_table(rng, n_ops, n_dies, attempts):
-        arr = np.sort(rng.uniform(0.0, 400.0, n_ops))
+        arr = np.sort(on_grid(rng.uniform(0.0, 400.0, n_ops)))
         kind = rng.choice([0.0, 0.0, 1.0, 2.0], size=n_ops)
         die = rng.integers(0, n_dies, n_ops).astype(np.float64)
-        dur = rng.uniform(10.0, 60.0, n_ops)
+        dur = on_grid(rng.uniform(10.0, 60.0, n_ops))
         att = (np.full(n_ops, 1.0) if attempts == 1
                else rng.integers(1, 6, n_ops).astype(np.float64))
-        tr = rng.uniform(5.0, 25.0, n_ops)
+        tr = on_grid(rng.uniform(5.0, 25.0, n_ops))
         # hp: host-read class for ~half the reads (GC copy-back reads
         # are low class, so reads with hp=0 are legal and exercised).
         hp = np.where((kind == 0.0) & (rng.random(n_ops) < 0.5),
@@ -281,3 +282,42 @@ class TestKernelVsReference:
                                  age_bound=bound)
             for g, w in zip(got, want):
                 assert np.array_equal(g, w)
+
+
+class TestCompileCache:
+    """The persistent compile cache honours JAX_COMPILATION_CACHE_DIR and
+    otherwise lands at one fixed directory inside the checkout."""
+
+    CHECKOUT = Path(__file__).resolve().parents[1]
+
+    def test_env_dir_wins(self, monkeypatch, tmp_path):
+        from repro.kernels.fcfs_core import ops
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert ops.compile_cache_dir() == str(tmp_path)
+
+    def test_default_is_fixed_in_checkout(self, monkeypatch):
+        from repro.kernels.fcfs_core import ops
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert ops.compile_cache_dir() == str(self.CHECKOUT / ".jax_cache")
+
+    @pytest.mark.parametrize("env", [False, True], ids=["unset", "set"])
+    def test_enable_sets_the_dir_only_when_env_unset(self, monkeypatch,
+                                                     tmp_path, env):
+        import jax
+
+        from repro.kernels.fcfs_core import ops
+
+        before = jax.config.jax_compilation_cache_dir
+        if env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(ops, "_COMP_CACHE_READY", False)
+        try:
+            ops._enable_persistent_cache()
+            want = before if env else str(self.CHECKOUT / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == want
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

@@ -21,17 +21,18 @@ try:
 except ImportError:
     HAVE_HYPOTHESIS = False
 
+from repro.flashsim.simtime import on_grid
 from repro.kernels.fcfs_core.ops import fused_core, pad_ops, pad_width
 from repro.kernels.fcfs_core.ref import fused_core_ref
 
 
 def _table(rng, n_ops, n_dies):
-    arr = np.sort(rng.uniform(0.0, 300.0, n_ops))
+    arr = np.sort(on_grid(rng.uniform(0.0, 300.0, n_ops)))
     kind = rng.choice([0.0, 0.0, 1.0, 2.0], size=n_ops)
     die = rng.integers(0, n_dies, n_ops).astype(np.float64)
-    dur = rng.uniform(10.0, 60.0, n_ops)
+    dur = on_grid(rng.uniform(10.0, 60.0, n_ops))
     att = rng.integers(1, 6, n_ops).astype(np.float64)
-    tr = rng.uniform(5.0, 25.0, n_ops)
+    tr = on_grid(rng.uniform(5.0, 25.0, n_ops))
     hp = np.where((kind == 0.0) & (rng.random(n_ops) < 0.5), 1.0, 0.0)
     return np.stack([arr, kind, die, dur, att, tr, hp], axis=1)
 
@@ -44,8 +45,8 @@ def _check_draw(draw):
     for _ in range(n_cells):
         lanes = [_table(rng, int(rng.integers(1, max_ops + 1)), n_dies)
                  for _ in range(n_lanes)]
-        tdma = float(rng.uniform(1.0, 8.0))
-        tecc = float(rng.uniform(1.0, 12.0))
+        tdma = on_grid(float(rng.uniform(1.0, 8.0)))
+        tecc = on_grid(float(rng.uniform(1.0, 12.0)))
         bound = (float(rng.choice([0.0, 2.0, 16.0, np.inf]))
                  if prio else None)
         cell_specs.append((lanes, tdma, tecc, bound))

@@ -41,8 +41,7 @@ from repro.flashsim.engine_batched import (
 )
 from repro.flashsim.runtime import (
     Cell,
-    _batched_sigs,
-    prewarm_batched,
+    _on_device,
     run_cells,
     sweep_to_json,
 )
@@ -253,39 +252,28 @@ class TestSweepJsonByteIdentity:
             assert "engine_selected" not in cell
 
 
-class TestPrewarmGating:
-    """prewarm compiles only variants the sweep will actually launch."""
+class TestDeviceRouting:
+    """Which cells may run the lockstep core — those stay in the process
+    that holds the accelerator (run_cells never hands them to a pool)."""
 
-    def test_auto_ineligible_warms_nothing(self):
-        cells = [Cell("batch", "websearch", (AGED,), MECHS, 0,
-                      DEFAULT_SSD, 200, "auto", "tokens", None, False)]
-        assert _batched_sigs(cells) == set()
-        assert prewarm_batched(cells) == 0
+    def test_auto_ineligible_stays_off_device(self):
+        cell = Cell("batch", "websearch", (AGED,), MECHS, 0,
+                    DEFAULT_SSD, 200, "auto", "tokens", None, False)
+        assert not _on_device(cell)
 
-    def test_array_engine_warms_nothing(self):
-        cells = [Cell("batch", "websearch", (AGED,), MECHS, 0,
-                      DEFAULT_SSD, 200, "array", None, None, False)]
-        assert _batched_sigs(cells) == set()
+    def test_array_engine_stays_off_device(self):
+        cell = Cell("batch", "websearch", (AGED,), MECHS, 0,
+                    DEFAULT_SSD, 200, "array", None, None, False)
+        assert not _on_device(cell)
 
-    def test_fused_lane_counts_included(self):
-        n_ch = DEFAULT_SSD.n_channels
-        n_dl = -(-DEFAULT_SSD.n_dies // n_ch)
-        cells = [Cell("batch", "websearch", (AGED, MODEST), MECHS, 0,
-                      DEFAULT_SSD, 200, "batched", None, None, False)]
-        sigs = _batched_sigs(cells)
-        # Per-cell variants for both pipelined classes...
-        assert (n_ch, n_dl, False, "fifo") in sigs
-        assert (n_ch, n_dl, True, "fifo") in sigs
-        # ...plus the widened fused variants: 2 conds x 2 serial mechs
-        # -> 4 cells, 2 conds x 1 pipelined mech -> 2 cells (both
-        # clamped to the fused cell cap).
-        cap = _fuse_cell_cap(n_ch)
-        assert (min(4, cap) * n_ch, n_dl, False, "fifo") in sigs
-        assert (min(2, cap) * n_ch, n_dl, True, "fifo") in sigs
+    @pytest.mark.parametrize("engine", ["batched", "auto"])
+    def test_eligible_cells_on_device(self, engine):
+        cell = Cell("batch", "websearch", (AGED, MODEST), MECHS, 0,
+                    DEFAULT_SSD, 200, engine, None, None, False)
+        assert _on_device(cell)
 
-    def test_fuse_off_drops_widened_variants(self):
-        cells = [Cell("batch", "websearch", (AGED, MODEST), MECHS, 0,
-                      DEFAULT_SSD, 200, "batched", None, None, False,
-                      fuse=False)]
-        n_ch = DEFAULT_SSD.n_channels
-        assert all(sig[0] == n_ch for sig in _batched_sigs(cells))
+    def test_fuse_off_still_on_device(self):
+        cell = Cell("batch", "websearch", (AGED, MODEST), MECHS, 0,
+                    DEFAULT_SSD, 200, "batched", None, None, False,
+                    fuse=False)
+        assert _on_device(cell)

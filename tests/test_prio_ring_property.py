@@ -27,6 +27,7 @@ pytest.importorskip(
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.flashsim.simtime import on_grid
 from repro.kernels.fcfs_core import fcfs_core, fcfs_core_ref
 from repro.kernels.fcfs_core.ops import pad_ops
 
@@ -42,12 +43,12 @@ _draws = st.tuples(
 
 
 def _table(rng, n_ops, n_dies, hp_frac):
-    arr = np.sort(rng.uniform(0.0, 300.0, n_ops))
+    arr = np.sort(on_grid(rng.uniform(0.0, 300.0, n_ops)))
     kind = rng.choice([0.0, 0.0, 1.0, 2.0], size=n_ops)
     die = rng.integers(0, n_dies, n_ops).astype(np.float64)
-    dur = rng.uniform(10.0, 60.0, n_ops)
+    dur = on_grid(rng.uniform(10.0, 60.0, n_ops))
     att = rng.integers(1, 6, n_ops).astype(np.float64)
-    tr = rng.uniform(5.0, 25.0, n_ops)
+    tr = on_grid(rng.uniform(5.0, 25.0, n_ops))
     hp = np.where((kind == 0.0) & (rng.random(n_ops) < hp_frac),
                   1.0, 0.0)
     return np.stack([arr, kind, die, dur, att, tr, hp], axis=1)

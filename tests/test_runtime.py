@@ -278,3 +278,51 @@ class TestCellExecutor:
         assert set(fp) == {"cpu_model", "cpu_count", "platform", "python",
                            "numpy"}
         assert fp["cpu_count"] >= 1
+
+
+class TestOneProcessPerChip:
+    """Cells that may run the lockstep core never reach a pool worker:
+    the accelerator belongs to the process that holds it."""
+
+    @staticmethod
+    def _no_pool(monkeypatch):
+        from repro.flashsim import runtime
+
+        def refuse(*a, **kw):
+            raise AssertionError("a pool was started for device cells")
+
+        monkeypatch.setattr(runtime, "ProcessPoolExecutor", refuse)
+
+    def test_run_cells_batched_workers_2_starts_no_pool(self, monkeypatch):
+        cells = [Cell("simulate", "websearch", (AGED,), (m,), 0,
+                      DEFAULT_SSD, 150, "batched")
+                 for m in ("baseline", "pr2ar2")]
+        want = run_cells(cells, workers=1)
+        self._no_pool(monkeypatch)
+        assert run_cells(cells, workers=2) == want
+
+    def test_run_compare_batched_workers_2_never_forks(self, monkeypatch):
+        kw = dict(mechanisms=("baseline", "pr2ar2"), seed=0,
+                  n_requests=150, engine="batched", fuse=False)
+        want = compare_mechanisms("websearch", AGED, **kw)
+        self._no_pool(monkeypatch)
+        assert compare_mechanisms("websearch", AGED, workers=2, **kw) == want
+
+    def test_mixed_cells_pool_only_the_array_ones(self, monkeypatch):
+        from repro.flashsim import runtime
+
+        device = [Cell("simulate", "websearch", (AGED,), ("pr2ar2",), s,
+                       DEFAULT_SSD, 150, "batched") for s in (0, 1)]
+        host = [Cell("simulate", "websearch", (AGED,), ("baseline",), s,
+                     DEFAULT_SSD, 150, "array") for s in (0, 1)]
+        submitted = []
+
+        class Recording(runtime.ProcessPoolExecutor):
+            def submit(self, fn, items):
+                submitted.extend(c.engine for _, c in items)
+                return super().submit(fn, items)
+
+        monkeypatch.setattr(runtime, "ProcessPoolExecutor", Recording)
+        cells = device + host
+        assert run_cells(cells, workers=2) == run_cells(cells, workers=1)
+        assert submitted == ["array", "array"]

@@ -31,6 +31,7 @@ from repro.flashsim.sched import (
     TokenBudgetQueue,
     get_scheduler,
 )
+from repro.flashsim.simtime import on_grid
 from repro.flashsim.ssd import SSDSim, _with_knobs, simulate
 from repro.flashsim.workloads import (
     RequestTrace,
@@ -143,22 +144,22 @@ class TestFCFSEquivalence:
         assert _stats_tuple(base) == _stats_tuple(knob)
 
     def test_prepass_gc_pinned_regression(self):
-        """Bit-exact pins captured from the pre-refactor monolithic engine
-        (PR 2) on churning GC cells: the layered fcfs engine must keep
-        reproducing them."""
+        """Bit-exact pins on churning GC cells, re-captured when simulated
+        time moved onto the 2**-10 us tick grid: the layered fcfs engine
+        must keep reproducing them."""
         w = dataclasses.replace(make_workloads()["rsrch"], n_requests=2500)
         s = simulate(w, AGED, "baseline", seed=0, cfg=GC_SSD)
-        assert s.mean_us == 21098.711579084185
-        assert s.p99_us == 201301.43863927457
-        assert s.read_p99_us == 175671.61373988495
-        assert s.mean_read_attempts == 13.797619047619047
+        assert s.mean_us == 21191.361431640624
+        assert s.p99_us == 201956.70213867162
+        assert s.read_p99_us == 176763.65718749998
+        assert s.mean_read_attempts == 13.865079365079366
         assert s.wa == 2.615843949044586
         assert (s.gc_invocations, s.blocks_erased) == (292, 292)
 
         w = dataclasses.replace(make_workloads()["prn"], n_requests=2500)
         s = simulate(w, AGED, "baseline", seed=0, cfg=GC_SSD)
-        assert s.mean_us == 7634.964356587506
-        assert s.read_p99_us == 150106.91833950975
+        assert s.mean_us == 7650.070820703125
+        assert s.read_p99_us == 150456.23523437456
         assert s.wa == 1.3831828442437923
         assert (s.gc_invocations, s.blocks_erased) == (102, 102)
 
@@ -380,7 +381,7 @@ class TestReadP99ExcludesGC:
         # GC ops ran (rid == -1 traffic existed) ...
         assert stats.gc_page_reads > 0
         # ... and the reported read p99 recomputes from host reads alone
-        resp = (sim.last_req_done_us - trace.arrival_us
+        resp = (sim.last_req_done_us - on_grid(trace.arrival_us)
                 + cfg.host_overhead_us)
         expect = float(np.percentile(resp[trace.is_read], 99))
         assert stats.read_p99_us == expect
