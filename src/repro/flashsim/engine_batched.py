@@ -46,6 +46,7 @@ from typing import Tuple
 
 import numpy as np
 
+from repro.flashsim import obs
 from repro.flashsim.engine import EngineResult
 from repro.flashsim.sched import SchedulerPolicy
 
@@ -234,19 +235,27 @@ def run_event_core_batched(
     """
     check_batched_supported(policy, bufs, online, validate)
 
-    tables, lane_idx, rid = _lane_tables(cfg, bufs)
-
-    from repro.kernels.fcfs_core import fcfs_core
-    from repro.kernels.fcfs_core.ops import pad_ops
+    from repro.kernels.fcfs_core.ops import (
+        count_steps, dispatch_caps, fused_core, pad_ops)
 
     mode, bound = policy.ring_lowering
-    ops = pad_ops(tables)
     n_dies_local = -(-cfg.n_dies // cfg.n_channels)
-    fin, diestat, lane = fcfs_core(
-        ops, n_dies_local, pipelined, bufs.tdma, bufs.tecc,
-        age_bound=bound if mode == "prio" else None)
-    return _assemble_result(cfg, rid, lane_idx, fin, diestat, lane,
-                            n_requests)
+    with obs.span("flashsim.pack"):
+        tables, lane_idx, rid = _lane_tables(cfg, bufs)
+        ops = pad_ops(tables)
+        steps = count_steps(ops)
+        caps = dispatch_caps(ops, n_dies_local, steps)
+        timing = np.tile(
+            [[bufs.tdma, bufs.tecc, bound if mode == "prio" else 0.0]],
+            (ops.shape[0], 1)).astype(np.float64)
+    # A single cell is a one-cell stack: the caps are the ones
+    # ``fcfs_core`` would derive, so the compiled variant is the same.
+    fin, diestat, lane = fused_core(
+        ops, n_dies_local, pipelined, timing, prio=(mode == "prio"),
+        caps=caps, steps=steps)
+    with obs.span("flashsim.assemble"):
+        return _assemble_result(cfg, rid, lane_idx, fin, diestat, lane,
+                                n_requests)
 
 
 @dataclasses.dataclass
@@ -340,67 +349,72 @@ def run_event_cores_fused(runs) -> list:
     ``fused_cells = len(its chunk)``.
     """
     from repro.kernels.fcfs_core.ops import (
-        count_steps, fused_core, pad_ops, pad_width, ring_caps,
-        _pow2_at_least)
+        count_steps, dispatch_caps, fused_core, pad_ops, pad_width)
 
-    prepped = []
-    for r in runs:
-        check_batched_supported(r.policy, r.bufs, None, False)
-        tables, lane_idx, rid = _lane_tables(r.cfg, r.bufs)
-        mode, bound = r.policy.ring_lowering
-        widest = max((t.shape[0] for t in tables), default=0)
-        prepped.append((r, tables, lane_idx, rid, mode, bound, widest))
+    with obs.span("flashsim.pack"):
+        prepped = []
+        for r in runs:
+            check_batched_supported(r.policy, r.bufs, None, False)
+            tables, lane_idx, rid = _lane_tables(r.cfg, r.bufs)
+            mode, bound = r.policy.ring_lowering
+            widest = max((t.shape[0] for t in tables), default=0)
+            prepped.append((r, tables, lane_idx, rid, mode, bound, widest))
 
-    # Group key = every static kernel parameter; per-cell dynamics
-    # (timing, bound, table contents) ride in traced operands.
-    groups = {}
-    for i, (r, tables, lane_idx, rid, mode, bound, widest) in \
-            enumerate(prepped):
-        n_ch = r.cfg.n_channels
-        key = (n_ch, -(-r.cfg.n_dies // n_ch), r.pipelined, mode,
-               pad_width(widest))
-        groups.setdefault(key, []).append(i)
+        # Group key = every static kernel parameter; per-cell dynamics
+        # (timing, bound, table contents) ride in traced operands.
+        groups = {}
+        for i, (r, tables, lane_idx, rid, mode, bound, widest) in \
+                enumerate(prepped):
+            n_ch = r.cfg.n_channels
+            key = (n_ch, -(-r.cfg.n_dies // n_ch), r.pipelined, mode,
+                   pad_width(widest))
+            groups.setdefault(key, []).append(i)
+
+        plan = []
+        for key, idxs in groups.items():
+            cells = []
+            for i in idxs:
+                _, tables, _, _, _, _, _ = prepped[i]
+                ops_c = pad_ops(tables, maxp=key[4])
+                cells.append((count_steps(ops_c), i, ops_c))
+            plan.append((key, _fuse_chunks(cells, key[0])))
 
     results = [None] * len(prepped)
-    for (n_ch, n_dies_local, pipelined, mode, maxp), idxs in \
-            groups.items():
-        cells = []
-        for i in idxs:
-            _, tables, _, _, _, _, _ = prepped[i]
-            ops_c = pad_ops(tables, maxp=maxp)
-            cells.append((count_steps(ops_c), i, ops_c))
-        for chunk in _fuse_chunks(cells, n_ch):
+    for (n_ch, n_dies_local, pipelined, mode, _), chunks in plan:
+        for chunk in chunks:
             C = len(chunk)
-            cell_ops = [ops_c for _, _, ops_c in chunk]
-            timing_rows = []
-            for _, i, _ in chunk:
-                r, _, _, _, _, bound, _ = prepped[i]
-                b = bound if mode == "prio" else 0.0
-                timing_rows.append(np.tile(
-                    [[r.bufs.tdma, r.bufs.tecc, b]],
-                    (n_ch, 1)))
-            stacked = np.concatenate(cell_ops, axis=0)
-            timing = np.concatenate(timing_rows,
-                                    axis=0).astype(np.float64)
+            with obs.span("flashsim.pack"):
+                cell_ops = [ops_c for _, _, ops_c in chunk]
+                timing_rows = []
+                for _, i, _ in chunk:
+                    r, _, _, _, _, bound, _ = prepped[i]
+                    b = bound if mode == "prio" else 0.0
+                    timing_rows.append(np.tile(
+                        [[r.bufs.tdma, r.bufs.tecc, b]],
+                        (n_ch, 1)))
+                stacked = np.concatenate(cell_ops, axis=0)
+                timing = np.concatenate(timing_rows,
+                                        axis=0).astype(np.float64)
 
-            # Chunk-wide static caps: ring bounds read off the stacked
-            # table in one pass — the lane-wise max over all cells, and
-            # pow2 bucketing commutes with max (ring pairing is by
-            # monotone counters and idle lanes no-op, so growing a cap
-            # never changes a cell's rows).  The chunk-max step count
-            # doubles as the stacked table's exact step bound (max over
-            # lanes), so the dispatch skips its recount.
-            steps = max(st for st, _, _ in chunk)
-            capq, capw = ring_caps(stacked, n_dies_local)
-            caps = (capq, capw, _pow2_at_least(max(steps, 16)))
+                # Chunk-wide static caps: ring bounds read off the
+                # stacked table in one pass — the lane-wise max over all
+                # cells, and pow2 bucketing commutes with max (ring
+                # pairing is by monotone counters and idle lanes no-op,
+                # so growing a cap never changes a cell's rows).  The
+                # chunk-max step count doubles as the stacked table's
+                # exact step bound (max over lanes), so the dispatch
+                # skips its recount.
+                steps = max(st for st, _, _ in chunk)
+                caps = dispatch_caps(stacked, n_dies_local, steps)
 
             fin, diestat, lane = fused_core(
                 stacked, n_dies_local, pipelined, timing,
                 prio=(mode == "prio"), caps=caps, steps=steps)
-            for j, (_, i, _) in enumerate(chunk):
-                r, _, lane_idx, rid, _, _, _ = prepped[i]
-                rows = slice(j * n_ch, (j + 1) * n_ch)
-                results[i] = _assemble_result(
-                    r.cfg, rid, lane_idx, fin[rows], diestat[rows],
-                    lane[rows], r.n_requests, fused_cells=C)
+            with obs.span("flashsim.assemble"):
+                for j, (_, i, _) in enumerate(chunk):
+                    r, _, lane_idx, rid, _, _, _ = prepped[i]
+                    rows = slice(j * n_ch, (j + 1) * n_ch)
+                    results[i] = _assemble_result(
+                        r.cfg, rid, lane_idx, fin[rows], diestat[rows],
+                        lane[rows], r.n_requests, fused_cells=C)
     return results

@@ -100,6 +100,7 @@ import numpy as np
 
 from repro.core import characterize as CH
 from repro.core.retry import RetryPolicy
+from repro.flashsim import obs
 from repro.flashsim.config import (
     DEFAULT_SSD,
     FaultConfig,
@@ -151,6 +152,7 @@ def _pctl(a: np.ndarray, qs) -> np.ndarray:
     return out
 
 
+@obs.spanned("flashsim.trace")
 def resolve_trace(
     workload: WorkloadLike, seed: int = 0, n_requests: Optional[int] = None
 ) -> RequestTrace:
@@ -344,6 +346,7 @@ class TraceExpansion:
         )
 
 
+@obs.spanned("flashsim.expand")
 def expand_trace(trace: RequestTrace, cfg: SSDConfig = DEFAULT_SSD) -> TraceExpansion:
     """Vectorized request -> page-op expansion (no per-request Python loop).
 
@@ -381,6 +384,7 @@ def expand_trace(trace: RequestTrace, cfg: SSDConfig = DEFAULT_SSD) -> TraceExpa
 class SSDSim:
     """One simulation run = (workload trace, operating condition, policy)."""
 
+    @obs.spanned("flashsim.sim_init")
     def __init__(
         self,
         cfg: SSDConfig = DEFAULT_SSD,
@@ -566,6 +570,7 @@ class SSDSim:
                     scale[worn & (wear == wv)] = self._scale_for(float(wv))
         return scale
 
+    @obs.spanned("flashsim.prepare")
     def _prepare(
         self,
         trace: RequestTrace,
@@ -635,10 +640,11 @@ class SSDSim:
             host_read_np = schedule.kind == _ftl.OP_READ
             read_like_np = schedule.kind <= _ftl.OP_GC_READ
             attempts_np = np.ones(P, np.int64)
-            attempts_np[read_like_np] = self._sample_attempts(
-                schedule.ptype[read_like_np],
-                schedule.wear_pec[read_like_np],
-            )
+            with obs.span("flashsim.sample_attempts"):
+                attempts_np[read_like_np] = self._sample_attempts(
+                    schedule.ptype[read_like_np],
+                    schedule.wear_pec[read_like_np],
+                )
             total_read_pages = int(host_read_np.sum())
             total_attempts = int(attempts_np[host_read_np].sum())
             tr_np = (self._tr_base[schedule.ptype]
@@ -706,7 +712,9 @@ class SSDSim:
 
             # Batched per-trace attempt schedule (admit-time work, up front).
             attempts_np = np.ones(P, np.int64)
-            attempts_np[read_mask] = self._sample_attempts(ex.ptype[read_mask])
+            with obs.span("flashsim.sample_attempts"):
+                attempts_np[read_mask] = self._sample_attempts(
+                    ex.ptype[read_mask])
             total_read_pages = int(read_mask.sum())
             total_attempts = int(attempts_np[read_mask].sum())
             tr_np = (self._tr_base * self.tr_scale)[ex.ptype]
@@ -822,6 +830,7 @@ class SSDSim:
                                  validate=validate, shard=shard)
         return self._finalize(prep, res)
 
+    @obs.spanned("flashsim.finalize")
     def _finalize(self, prep: "_PreparedRun", res) -> SimStats:
         """Assemble :class:`SimStats` from one engine result — the back
         half of :meth:`run` (pure code motion from it; any change here
@@ -1125,6 +1134,7 @@ def _make_sim(cfg, condition, mechanism, seed, engine):
     )
 
 
+@obs.spanned("flashsim.call", api="simulate")
 def simulate(
     workload: WorkloadLike,
     condition: OperatingCondition,
@@ -1197,6 +1207,7 @@ def simulate(
     return sim.run(trace, validate=validate)
 
 
+@obs.spanned("flashsim.call", api="compare_mechanisms")
 def compare_mechanisms(
     workload: WorkloadLike,
     condition: OperatingCondition,
@@ -1270,6 +1281,7 @@ def compare_mechanisms(
     return out
 
 
+@obs.spanned("flashsim.call", api="simulate_batch")
 def simulate_batch(
     workload: WorkloadLike,
     conditions: Iterable[OperatingCondition],

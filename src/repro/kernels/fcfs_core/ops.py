@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.flashsim import obs
 from repro.flashsim.simtime import from_ticks, to_ticks
 from repro.kernels.fcfs_core.kernel import (_ARR, _DUR, _GDT, _KIND, _TR,
                                             NEVER, fcfs_core_fwd)
@@ -96,10 +97,18 @@ _core_jit = jax.jit(
                      "prio", "wide"))
 
 
-#: Number of kernel dispatches issued by this process (both the
-#: per-run and the fused entry points).  Read by tests/CI to assert the
-#: single-dispatch accounting of the fused sweep path.
-KERNEL_DISPATCHES = 0
+#: Static keys of the core variants this process has dispatched.
+_VARIANTS = set()
+
+
+def __getattr__(name):
+    # ``KERNEL_DISPATCHES``: the number of kernel dispatches issued by
+    # this process (both the per-run and the fused entry points), read
+    # from the recorder's ``dispatches`` counter.
+    if name == "KERNEL_DISPATCHES":
+        return obs.total("dispatches")
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 #: Lane counts above this use the batched-scatter (``wide``) carry
 #: updates.  The unrolled per-lane dynamic_update_slice is measurably
@@ -168,8 +177,8 @@ def augment_ops(ops: np.ndarray, pipelined: bool) -> np.ndarray:
         [ops, np.stack([gdt, gk0, grem0], axis=2)], axis=2)
 
 
-def count_steps(ops: np.ndarray) -> int:
-    """Lockstep step bound: max over lanes of admissions + heap pops.
+def lane_steps(ops: np.ndarray) -> np.ndarray:
+    """Per-lane lockstep step bound: admissions + heap pops of each lane.
 
     Per op the interpreter pops ``attempts + 1`` events for a read
     (senses + release), 2 for a write (transfer-landed + release), and 1
@@ -185,7 +194,13 @@ def count_steps(ops: np.ndarray) -> int:
                       np.where(kind == 1.0, 2.0,
                                np.where(kind == 2.0, 1.0, 0.0)))
     n_adm = (kind != 3.0).sum(axis=1)
-    return int((n_adm + per_op.sum(axis=1)).max(initial=0.0))
+    return (n_adm + per_op.sum(axis=1)).astype(np.int64)
+
+
+def count_steps(ops: np.ndarray) -> int:
+    """Lockstep step bound of a dispatch: the max of :func:`lane_steps`
+    (every lane runs it; finished lanes no-op)."""
+    return int(lane_steps(ops).max(initial=0))
 
 
 def _pow2_at_least(n: int) -> int:
@@ -219,6 +234,12 @@ def ring_caps(ops: np.ndarray, n_dies: int):
         per_die = int(np.bincount(flat).max())
     writes = int((kind == 1.0).sum(axis=1).max(initial=0.0))
     return _pow2_at_least(max(per_die, 4)), _pow2_at_least(max(writes, 4))
+
+
+def dispatch_caps(ops: np.ndarray, n_dies: int, steps: int):
+    """Static ``(capq, capw, capsteps)`` of a dispatch: :func:`ring_caps`
+    and the step count bucketed to a power of two (floor 16)."""
+    return (*ring_caps(ops, n_dies), _pow2_at_least(max(steps, 16)))
 
 
 def _tick_table(aug: np.ndarray) -> np.ndarray:
@@ -256,38 +277,56 @@ def _dispatch(ops: np.ndarray, n_dies: int, pipelined: bool,
     stacking; the max over a chunk's cells equals the stacked count).
     Returns numpy ``(fin, diestat, lane)`` in µs (counts as f64).
     """
-    global KERNEL_DISPATCHES
     _enable_persistent_cache()
     if steps is None:
         steps = count_steps(ops)
     if caps is None:
-        capq, capw = ring_caps(ops, n_dies)
-        capsteps = _pow2_at_least(max(steps, 16))
-    else:
-        capq, capw, capsteps = caps
-        if steps > capsteps:
-            raise ValueError(f"steps {steps} > capsteps {capsteps}")
+        caps = dispatch_caps(ops, n_dies, steps)
+    capq, capw, capsteps = caps
+    if steps > capsteps:
+        raise ValueError(f"steps {steps} > capsteps {capsteps}")
     L, maxp = ops.shape[0], ops.shape[1]
-    table = _tick_table(augment_ops(ops, pipelined))
-    tick_timing = _tick_timing(timing)
-    with jax.enable_x64(True):
-        log, diestat, lane = _core_jit(
-            jnp.asarray(table), jnp.int32(steps), jnp.asarray(tick_timing),
-            n_dies=n_dies, capq=capq, capw=capw, capsteps=capsteps,
-            pipelined=pipelined, prio=prio, wide=L > _WIDE_LANES)
-        log, diestat, lane = (np.asarray(log), np.asarray(diestat),
-                              np.asarray(lane))
-    KERNEL_DISPATCHES += 1
-    # Scatter the per-step completion log into the per-op fin table.
-    # Each real op id appears at most once; idle rows carry the sink id
-    # maxp, zeroed afterwards.  Rows past ``steps`` were never written
-    # (all-sink) — skip them.
-    fin = np.zeros((L, maxp + 1), dtype=np.float64)
-    fin[np.arange(L)[None, :], log[:steps, L:]] = from_ticks(log[:steps, :L])
-    fin[:, maxp] = 0.0
-    lane_us = np.concatenate(
-        [from_ticks(lane[:, :2]), lane[:, 2:].astype(np.float64)], axis=1)
-    return fin, from_ticks(diestat), lane_us
+    wide = L > _WIDE_LANES
+    variant = (n_dies, capq, capw, capsteps, pipelined, prio, wide, L, maxp)
+    new = variant not in _VARIANTS
+    with obs.span("flashsim.dispatch"):
+        with jax.enable_x64(True):
+            with obs.span("flashsim.stage"):
+                table = jnp.asarray(_tick_table(augment_ops(ops, pipelined)))
+                tick_timing = jnp.asarray(_tick_timing(timing))
+            # The core's wall time: np.asarray waits for the device.
+            with obs.span("flashsim.core", lanes=L, steps=steps,
+                          capsteps=capsteps, variant=variant,
+                          new_variant=new):
+                log, diestat, lane = _core_jit(
+                    table, jnp.int32(steps), tick_timing,
+                    n_dies=n_dies, capq=capq, capw=capw, capsteps=capsteps,
+                    pipelined=pipelined, prio=prio, wide=wide)
+                log, diestat, lane = (np.asarray(log), np.asarray(diestat),
+                                      np.asarray(lane))
+        _VARIANTS.add(variant)
+        obs.count("dispatches")
+        with obs.span("flashsim.unpack"):
+            # Scatter the per-step completion log into the per-op fin
+            # table.  Each real op id appears at most once; idle rows
+            # carry the sink id maxp, zeroed afterwards.  Rows past
+            # ``steps`` were never written (all-sink) — skip them.
+            fin = np.zeros((L, maxp + 1), dtype=np.float64)
+            fin[np.arange(L)[None, :], log[:steps, L:]] = from_ticks(
+                log[:steps, :L])
+            fin[:, maxp] = 0.0
+            lane_us = np.concatenate(
+                [from_ticks(lane[:, :2]), lane[:, 2:].astype(np.float64)],
+                axis=1)
+            diestat_us = from_ticks(diestat)
+        if obs.active():
+            # Every lane runs ``steps``; its own bound is the useful part.
+            obs.count("lanes", L)
+            obs.count("lane_steps_run", L * steps)
+            obs.count("lane_steps_useful", int(lane_steps(ops).sum()))
+            if new:
+                obs.count("core_variants_new")
+    return fin, diestat_us, lane_us
 
 
 def fcfs_core(ops: np.ndarray, n_dies: int, pipelined: bool,
