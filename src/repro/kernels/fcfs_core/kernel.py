@@ -1,5 +1,5 @@
 """Lockstep core for the sched-aware open-loop shard loop: one jitted
-XLA program, the same on CPU and TPU.
+XLA program on CPU and TPU, with a carry lowering for each (below).
 
 One invocation advances *all* channel shards of a run in lockstep:
 the lane dimension (axis 0 everywhere) is the shard/channel, and each
@@ -59,11 +59,11 @@ State layout (int64, times in ticks; ``NEVER`` is "no event"):
                         (2*CAPQ): the *host-read* (hi) ring lives in
                         slots [0, CAPQ) and the low class (programs, GC
                         copy-back, erases) in [CAPQ, 2*CAPQ) of the
-                        *same* buffer — one push scatter and one pop
-                        gather per step regardless of class, instead of
-                        a second buffer costing its own L per-lane
-                        updates.  Per-class occupancy is bounded by the
-                        per-die total, so CAPQ bounds both regions.
+                        *same* buffer — one push write and one pop
+                        read per step regardless of class, instead of
+                        a second buffer with writes of its own.
+                        Per-class occupancy is bounded by the per-die
+                        total, so CAPQ bounds both regions.
   acq   (L, CAPW+1, 4)— ring of in-flight write transfers [done, seq,
                         op, die]; CAPW bounds the writes of one lane;
                         slot CAPW is the masked-write sink.
@@ -94,14 +94,44 @@ changes only at ring pops — admissions and ACQ landings that grant a
 free die directly never consult the queue object in the interpreter, so
 they never touch the counter here either.
 
-Every scatter into the carry is *unconditional*: inactive lanes are
-redirected to a sink row/slot instead of blending with the gathered
-current value, so each carry buffer has the scatter as its only
-consumer and XLA updates it in place across ``fori_loop`` steps
-(masked blends forced a full copy of every buffer per step).  The FIFO
-push runs before the pop gather for the same reason — a lane popping
+Carry lowering
+--------------
+Each step writes one element (row) of three carry buffers per lane —
+``state`` at the lane's die row, ``fifo`` at its pushed (die, slot),
+``acq`` at its ACQ slot — and reads one of each.  The static
+``lowering`` argument picks how; the written values, and so every
+result, are identical in all three:
+
+  * ``"unrolled"`` — one ``dynamic_update_slice`` per lane (static lane,
+    computed row) and a gather per read.  The cheapest in-place update
+    XLA:CPU emits for a handful of computed indices: both the generic
+    scatter and a one-hot select measured slower there.
+  * ``"scatter"`` — one batched scatter per buffer over unique, sorted
+    lane indices.  On XLA:CPU beyond some 64 lanes, where the unroll
+    would bloat the loop body.
+  * ``"select"`` — one lane-parallel ``where`` over the one-hot of the
+    small die/slot axes per write, and a masked sum over the same
+    one-hot per read (one non-zero term: exact).  For the TPU: there
+    each unrolled update waits on its lane's indices, handed from the
+    vector unit to the scalar unit one lane at a time, so the unroll's
+    step time grows with the lane count; the selects have no per-lane
+    code, and the loop body's size does not depend on L (on a TPU v5e
+    an 8-lane websearch call's step fell from 52.9 to 10.3 µs).  Their
+    work does: each step rewrites and reads every buffer whole, and
+    the ``fifo`` ring dominates, L·(D+1)·CAPQ elements (twice that
+    under prio) against one element per lane for the other two
+    lowerings.  CAPQ is the deepest die queue, so it grows with the
+    trace's length; on a v5e the selects still beat the unroll 3.6–14×
+    at CAPQ 4096 (``benchmarks/core_lowering.py``).
+
+Every write is *unconditional*: inactive lanes are redirected to a sink
+row/slot instead of blending with the gathered current value, so under
+the update-slice and scatter lowerings each carry buffer has the write
+as its only consumer and XLA updates it in place across ``fori_loop``
+steps (masked blends forced a full copy of every buffer per step).  The
+FIFO push runs before the pop read for the same reason — a lane popping
 this step never pushes, so reading the pushed buffer is semantically
-identical, and it keeps the scatter the buffer's only carry consumer.
+identical, and it keeps the write the buffer's only carry consumer.
 """
 
 from __future__ import annotations
@@ -121,8 +151,55 @@ import jax.numpy as jnp
 NEVER = 2 ** 62
 
 
+#: The carry lowerings (see the module docstring).
+LOWERINGS = ("unrolled", "scatter", "select")
+
+
+def _hot(shape, idx):
+    """(L, *shape[1:1+k]) one-hot of lane l's index ``idx[i][l]`` on axis
+    ``1 + i`` of a buffer of ``shape``, for k = ``len(idx)``."""
+    k = len(idx)
+    hot = None
+    for ax, i in enumerate(idx, start=1):
+        iota = jax.lax.broadcasted_iota(jnp.int32, shape[:1 + k], ax)
+        eq = iota == i.reshape((-1,) + (1,) * k)
+        hot = eq if hot is None else hot & eq
+    return hot
+
+
+def _get(buf, idx, lowering):
+    """``buf[l, idx[0][l], ..., idx[k-1][l]]`` for every lane l."""
+    if lowering != "select":
+        return buf[(jnp.arange(buf.shape[0]), *idx)]
+    hot = _hot(buf.shape, idx)
+    hot = hot.reshape(hot.shape + (1,) * (buf.ndim - hot.ndim))
+    return jnp.where(hot, buf, 0).sum(axis=tuple(range(1, 1 + len(idx))))
+
+
+def _set(buf, idx, val, lowering):
+    """``buf`` with ``buf[l, idx[0][l], ..., idx[k-1][l]] = val[l]`` for
+    every lane l (indices int32, at most one element per lane)."""
+    L, k = buf.shape[0], len(idx)
+    rest = buf.shape[1 + k:]
+    if lowering == "select":
+        hot = _hot(buf.shape, idx)
+        hot = hot.reshape(hot.shape + (1,) * len(rest))
+        return jnp.where(hot, val.reshape((L,) + (1,) * k + rest), buf)
+    if lowering == "scatter":
+        return buf.at[(jnp.arange(L), *idx)].set(
+            val, unique_indices=True, indices_are_sorted=True)
+    zeros = (jnp.int32(0),) * len(rest)
+    for l in range(L):
+        buf = jax.lax.dynamic_update_slice(
+            buf, val[l].reshape((1,) * (1 + k) + rest),
+            (jnp.int32(l), *(i[l] for i in idx), *zeros))
+    return buf
+
+
 def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
-          prio, wide):
+          prio, lowering):
+    if lowering not in LOWERINGS:
+        raise ValueError(f"lowering {lowering!r} not in {LOWERINGS}")
     L, maxp, _ = ops.shape
     D = n_dies
     lanes = jnp.arange(L)
@@ -141,7 +218,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
         # ---- candidate selection: per-die events + ACQ head ----------
         evt = state[:, :D, _EVT]
         evseq = state[:, :D, _EVSEQ]
-        aq_row = acq[lanes, aq_head % capw]
+        aq_row = _get(acq, (aq_head % capw,), lowering)
         aq_empty = aq_head >= aq_tail
         aq_t = jnp.where(aq_empty, NEVER, aq_row[:, 0])
         aq_sq = jnp.where(aq_empty, NEVER, aq_row[:, 1])
@@ -174,7 +251,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
         tgt = jnp.where(take_adm & (is_r | is_e), a_die,
                         jnp.where(ev_die, widx.astype(jnp.int32),
                                   jnp.where(ev_acq, acq_die, D)))
-        row = state[lanes, tgt]
+        row = _get(state, (tgt,), lowering)
 
         if prio:
             hi_empty = row[:, _QTAIL] == row[:, _QHEAD]
@@ -197,21 +274,10 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
 
         # write admission: ACQ push at its DMA-done time, unconditional
         # (non-write lanes land in the sink slot capw, never read).
-        # Per-lane dynamic_update_slice with a static lane index is the
-        # cheapest in-place update XLA:CPU will emit for a handful of
-        # computed row indices — both the generic scatter op and a
-        # one-hot blend over the ring measured slower.
         aq_slot = jnp.where(is_w, aq_tail % capw, capw)
         aq_new = jnp.stack([c_done, seqc, ai.astype(i64),
                             adm_row[:, _DIE]], axis=1)
-        if wide:
-            acq = acq.at[lanes, aq_slot].set(
-                aq_new, unique_indices=True, indices_are_sorted=True)
-        else:
-            for l in range(L):
-                acq = jax.lax.dynamic_update_slice(
-                    acq, aq_new[l][None, None, :],
-                    (jnp.int32(l), aq_slot[l], jnp.int32(0)))
+        acq = _set(acq, (aq_slot,), aq_new, lowering)
         aq_tail = aq_tail + is_w.astype(jnp.int32)
 
         # -- sense / copy handler --
@@ -243,7 +309,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
             # lookup.  Non-pushing lanes read a harmless row (push_die
             # is the sink for them).  Class picks the ring *region* of
             # the shared buffer: hi at [0, capq), lo at [capq, 2*capq)
-            # — one scatter per lane either way.
+            # — one write either way.
             push_hp = ops[lanes, push_val, _HP] == 1
             push_hi = queue_push & push_hp
             push_lo = queue_push & ~push_hp
@@ -252,14 +318,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
                 capq + row[:, _QTAIL2].astype(jnp.int32) % capq)
         else:
             push_slot = row[:, _QTAIL].astype(jnp.int32) % capq
-        if wide:
-            fifo = fifo.at[lanes, push_die, push_slot].set(
-                push_val, unique_indices=True, indices_are_sorted=True)
-        else:
-            for l in range(L):
-                fifo = jax.lax.dynamic_update_slice(
-                    fifo, push_val[l].reshape(1, 1, 1),
-                    (jnp.int32(l), push_die[l], push_slot[l]))
+        fifo = _set(fifo, (push_die, push_slot), push_val, lowering)
 
         q_nonempty = ~q_empty
         grant2 = ev_rel & q_nonempty
@@ -279,7 +338,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
                 row[:, _QHEAD].astype(jnp.int32) % capq)
         else:
             qh = row[:, _QHEAD].astype(jnp.int32) % capq
-        o2 = fifo[lanes, tgt, qh]
+        o2 = _get(fifo, (tgt, qh), lowering)
 
         # one gather serves every grant source: popped op, admitted op,
         # or the ACQ-landed op (masked lanes read a harmless row)
@@ -335,22 +394,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
         if prio:
             cols += [new_qhead2, new_qtail2, new_byp]
         new_row = jnp.stack([c.astype(i64) for c in cols], axis=1)
-        # Per-lane dynamic_update_slice (static lane, computed die row):
-        # measurably cheaper than both XLA:CPU's generic scatter and a
-        # one-hot blend at shard-core lane counts, and still updated in
-        # place.  Under the ``wide`` lowering (fused sweeps stack cells
-        # into dozens of lanes) the unroll would bloat the loop body,
-        # so the same update is emitted as one batched scatter — lane
-        # indices are unique and sorted, so the written values and the
-        # in-place carry update are identical either way.
-        if wide:
-            state = state.at[lanes, tgt].set(
-                new_row, unique_indices=True, indices_are_sorted=True)
-        else:
-            for l in range(L):
-                state = jax.lax.dynamic_update_slice(
-                    state, new_row[l][None, None, :],
-                    (jnp.int32(l), tgt[l], jnp.int32(0)))
+        state = _set(state, (tgt,), new_row, lowering)
 
         # fin events: final sense (reads) or release of a non-read.
         # Logged as one (2L,) row per step — the fin table is never
@@ -401,7 +445,7 @@ def _core(ops, steps, timing, *, n_dies, capq, capw, capsteps, pipelined,
 
 
 def fcfs_core_fwd(ops, steps, timing, *, n_dies, capq, capw, capsteps,
-                  pipelined, prio=False, wide=False):
+                  pipelined, prio=False, lowering="unrolled"):
     """Run the lockstep shard core (traced under ``jax.enable_x64``).
 
     ``ops``: (L, MAXP, 10) int64 augmented padded op table, times in
@@ -415,15 +459,15 @@ def fcfs_core_fwd(ops, steps, timing, *, n_dies, capq, capw, capsteps,
     static FIFO/ACQ ring capacities (host-computed bounds: max ops on
     one die / max writes on one lane); ``capsteps`` — static log length,
     a power of two >= steps.  ``prio`` selects the dual-ring scheduler
-    lowering and ``wide`` the batched-scatter carry updates for large
-    fused lane counts (both static: distinct compiled programs,
-    identical results).  Returns ``(log, diestat, lane)``, all int64:
-    the per-step completion log (scatter it into the per-op ``fin``
-    table host-side), per-die [tot, busy], and per-lane [ch_busy,
-    ch_tot, n_events, seqc].  Runs under the ``fcfs_core`` named scope,
-    which is how a profile finds it.
+    lowering and ``lowering`` the carry lowering, one of
+    :data:`LOWERINGS` (module docstring; both static: distinct compiled
+    programs, identical results).  Returns ``(log, diestat, lane)``,
+    all int64: the per-step completion log (scatter it into the per-op
+    ``fin`` table host-side), per-die [tot, busy], and per-lane
+    [ch_busy, ch_tot, n_events, seqc].  Runs under the ``fcfs_core``
+    named scope, which is how a profile finds it.
     """
     with jax.named_scope("fcfs_core"):
         return _core(ops, steps, timing, n_dies=n_dies, capq=capq,
                      capw=capw, capsteps=capsteps, pipelined=pipelined,
-                     prio=prio, wide=wide)
+                     prio=prio, lowering=lowering)

@@ -3,12 +3,14 @@
 ``fcfs_core`` takes the padded per-lane op table as numpy (µs, f64),
 converts its times to int64 ticks of :mod:`repro.flashsim.simtime` —
 raising on a time off the tick grid, never rounding — runs the core as
-one jitted XLA program, the same on CPU and TPU, and returns numpy
-results in µs.  There is no interpret mode and no backend switch: the
-program runs on JAX's default device, and fails there rather than
-falling back.  All JAX work happens inside a scoped ``jax.enable_x64``
-context so the int64 requirement never leaks into the process-global
-JAX config (the characterization compiles under the default 32 bits).
+one jitted XLA program on CPU and TPU alike, and returns numpy results
+in µs.  There is no interpret mode and no fallback: the program runs on
+JAX's default device, and fails there rather than falling back; the
+only thing the platform picks is how the program writes its carry
+(:func:`carry_lowering`), which never changes a result.  All JAX work
+happens inside a scoped ``jax.enable_x64`` context so the int64
+requirement never leaks into the process-global JAX config (the
+characterization compiles under the default 32 bits).
 
 Integer ticks are what make the result exact on every backend: the
 interpreter's f64 add/max on on-grid values is exact, and so is the
@@ -18,7 +20,9 @@ is not IEEE f64; its emulated s64 add/max/compare are exact.
 Compiled-variant reuse (the dispatch-overhead contract)
 -------------------------------------------------------
 The core is jit-cached per (lane count, padded width, die count, ring
-capacities, pipelined flag, scheduler lowering); the step count, timing
+capacities, pipelined flag, scheduler lowering, carry lowering — the
+last chosen by :func:`carry_lowering` from the device's platform and
+the lane count); the step count, timing
 constants, and aging bound are *traced*, so different workload sizes,
 timing models, and ``host_prio_aged`` bounds all reuse one executable.
 Every static shape is bucketed to a power of two with a small floor
@@ -94,7 +98,7 @@ def _enable_persistent_cache() -> None:
 _core_jit = jax.jit(
     fcfs_core_fwd,
     static_argnames=("n_dies", "capq", "capw", "capsteps", "pipelined",
-                     "prio", "wide"))
+                     "prio", "lowering"))
 
 
 #: Static keys of the core variants this process has dispatched.
@@ -110,13 +114,32 @@ def __getattr__(name):
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
-#: Lane counts above this use the batched-scatter (``wide``) carry
-#: updates.  The unrolled per-lane dynamic_update_slice is measurably
-#: faster everywhere the fused sweep operates (its cell cap keeps
-#: stacked dispatches at or under 64 lanes), so ``wide`` only takes
-#: over beyond that — oversized single-cell topologies where the
-#: unroll would bloat the traced loop body.
+#: Lane counts above this take the ``"scatter"`` carry lowering on CPU.
+#: The unrolled per-lane dynamic_update_slice is measurably faster there
+#: everywhere the fused sweep operates (its cell cap keeps stacked
+#: dispatches at or under 64 lanes), so the scatter only takes over
+#: beyond that — oversized single-cell topologies where the unroll
+#: would bloat the traced loop body.
 _WIDE_LANES = 64
+
+
+def carry_lowering(platform: str, lanes: int) -> str:
+    """The core's carry lowering for a dispatch of ``lanes`` lanes on a
+    device of ``platform`` (see :mod:`repro.kernels.fcfs_core.kernel`).
+
+    ``"select"`` on the TPU at any lane count: there every per-lane
+    update waits on its indices' handoff to the scalar unit.  Elsewhere
+    ``"unrolled"`` up to :data:`_WIDE_LANES` lanes, ``"scatter"`` above.
+    """
+    if platform == "tpu":
+        return "select"
+    return "unrolled" if lanes <= _WIDE_LANES else "scatter"
+
+
+def _platform(x) -> str:
+    """Platform of the one device that holds array ``x``."""
+    (dev,) = x.devices()
+    return dev.platform
 
 
 def pad_width(widest: int) -> int:
@@ -286,14 +309,15 @@ def _dispatch(ops: np.ndarray, n_dies: int, pipelined: bool,
     if steps > capsteps:
         raise ValueError(f"steps {steps} > capsteps {capsteps}")
     L, maxp = ops.shape[0], ops.shape[1]
-    wide = L > _WIDE_LANES
-    variant = (n_dies, capq, capw, capsteps, pipelined, prio, wide, L, maxp)
-    new = variant not in _VARIANTS
     with obs.span("flashsim.dispatch"):
         with jax.enable_x64(True):
             with obs.span("flashsim.stage"):
                 table = jnp.asarray(_tick_table(augment_ops(ops, pipelined)))
                 tick_timing = jnp.asarray(_tick_timing(timing))
+            lowering = carry_lowering(_platform(table), L)
+            variant = (n_dies, capq, capw, capsteps, pipelined, prio,
+                       lowering, L, maxp)
+            new = variant not in _VARIANTS
             # The core's wall time: np.asarray waits for the device.
             with obs.span("flashsim.core", lanes=L, steps=steps,
                           capsteps=capsteps, variant=variant,
@@ -301,7 +325,7 @@ def _dispatch(ops: np.ndarray, n_dies: int, pipelined: bool,
                 log, diestat, lane = _core_jit(
                     table, jnp.int32(steps), tick_timing,
                     n_dies=n_dies, capq=capq, capw=capw, capsteps=capsteps,
-                    pipelined=pipelined, prio=prio, wide=wide)
+                    pipelined=pipelined, prio=prio, lowering=lowering)
                 log, diestat, lane = (np.asarray(log), np.asarray(diestat),
                                       np.asarray(lane))
         _VARIANTS.add(variant)

@@ -205,8 +205,16 @@ class TestExplicitRejection:
                                engine="batched", scheduler="tokens")
 
 
+def _with_select(values):
+    """Each value on the platform's carry lowering (None), then forced
+    to the TPU's ``"select"`` lowering."""
+    return ([(v, None) for v in values]
+            + [(v, "select") for v in values])
+
+
 class TestKernelVsReference:
-    """Lockstep kernel vs the independent pure-Python oracle, bitwise."""
+    """Lockstep kernel vs the independent pure-Python oracle, bitwise,
+    on the platform's carry lowering and on the TPU's."""
 
     @staticmethod
     def _random_table(rng, n_ops, n_dies, attempts):
@@ -223,13 +231,26 @@ class TestKernelVsReference:
                       1.0, 0.0)
         return np.stack([arr, kind, die, dur, att, tr, hp], axis=1)
 
-    @pytest.mark.parametrize("pipelined", [False, True])
+    @staticmethod
+    def _force(monkeypatch, lowering):
+        # None keeps the carry lowering this platform picks.
+        from repro.kernels.fcfs_core import ops
+
+        if lowering is not None:
+            monkeypatch.setattr(ops, "carry_lowering",
+                                lambda platform, lanes: lowering)
+
+    @pytest.mark.parametrize("pipelined,lowering", _with_select(
+        [False, True]), ids=["False", "True", "False-select",
+                             "True-select"])
     @pytest.mark.parametrize("attempts", [1, None],
                              ids=["rel0-single-attempt", "multi-attempt"])
-    def test_bitwise_parity_random_tables(self, pipelined, attempts):
+    def test_bitwise_parity_random_tables(self, pipelined, attempts,
+                                          lowering, monkeypatch):
         from repro.kernels.fcfs_core import fcfs_core, fcfs_core_ref
         from repro.kernels.fcfs_core.ops import pad_ops
 
+        self._force(monkeypatch, lowering)
         rng = np.random.default_rng(42 if pipelined else 7)
         n_dies = 4
         for _ in range(3):
@@ -242,17 +263,21 @@ class TestKernelVsReference:
             for g, w in zip(got, want):
                 assert np.array_equal(g, w)
 
-    @pytest.mark.parametrize("age_bound", [0.0, 1.0, 4.0, 1e18],
-                             ids=["bound0", "bound1", "bound4",
-                                  "unbounded"])
+    @pytest.mark.parametrize(
+        "age_bound,lowering", _with_select([0.0, 1.0, 4.0, 1e18]),
+        ids=["bound0", "bound1", "bound4", "unbounded", "bound0-select",
+             "bound1-select", "bound4-select", "unbounded-select"])
     @pytest.mark.parametrize("pipelined", [False, True])
     def test_priority_rings_parity_random_tables(self, pipelined,
-                                                 age_bound):
+                                                 age_bound, lowering,
+                                                 monkeypatch):
         # Aging-boundary corners: bound 0 bypasses whenever low work
         # waits behind a host read, bound 1e18 never does (plain
         # host_prio); 1 and 4 sit on the counter-reset boundary.
         from repro.kernels.fcfs_core import fcfs_core, fcfs_core_ref
         from repro.kernels.fcfs_core.ops import pad_ops
+
+        self._force(monkeypatch, lowering)
 
         rng = np.random.default_rng(int(age_bound) % 97 +
                                     (13 if pipelined else 0))
@@ -269,19 +294,65 @@ class TestKernelVsReference:
             for g, w in zip(got, want):
                 assert np.array_equal(g, w)
 
-    def test_empty_and_single_lane_corners(self):
+    def test_empty_and_single_lane_corners(self, monkeypatch):
         from repro.kernels.fcfs_core import fcfs_core, fcfs_core_ref
         from repro.kernels.fcfs_core.ops import pad_ops
 
         rng = np.random.default_rng(0)
         lanes = [np.zeros((0, 7)), self._random_table(rng, 5, 2, None)]
         ops = pad_ops(lanes)
-        for bound in (None, 2.0):
-            got = fcfs_core(ops, 2, False, 3.0, 5.0, age_bound=bound)
-            want = fcfs_core_ref(ops, 2, False, 3.0, 5.0,
-                                 age_bound=bound)
-            for g, w in zip(got, want):
-                assert np.array_equal(g, w)
+        for lowering in (None, "select"):
+            with monkeypatch.context() as m:
+                self._force(m, lowering)
+                for bound in (None, 2.0):
+                    got = fcfs_core(ops, 2, False, 3.0, 5.0,
+                                    age_bound=bound)
+                    want = fcfs_core_ref(ops, 2, False, 3.0, 5.0,
+                                         age_bound=bound)
+                    for g, w in zip(got, want):
+                        assert np.array_equal(g, w)
+
+
+class TestCarryLowering:
+    """The core's carry lowering follows the device's platform and the
+    lane count, nothing else."""
+
+    @pytest.mark.parametrize("platform,lanes,want", [
+        ("tpu", 1, "select"), ("tpu", 64, "select"),
+        ("tpu", 4096, "select"), ("cpu", 8, "unrolled"),
+        ("cpu", 64, "unrolled"), ("cpu", 65, "scatter"),
+    ])
+    def test_choice(self, platform, lanes, want):
+        from repro.kernels.fcfs_core import ops
+
+        assert ops.carry_lowering(platform, lanes) == want
+
+    @pytest.mark.parametrize("platform,want", [("tpu", "select"),
+                                               ("cpu", "unrolled")])
+    def test_dispatch_takes_the_platforms_lowering(self, monkeypatch,
+                                                   platform, want):
+        # The platform is patched: a chip is not needed to see which
+        # lowering a TPU dispatch asks for, and the CPU runs it exactly.
+        from repro.kernels.fcfs_core import fcfs_core, fcfs_core_ref, ops
+        from repro.kernels.fcfs_core.ops import pad_ops
+
+        seen = []
+        core_jit = ops._core_jit
+
+        def spy(*args, **kw):
+            seen.append(kw["lowering"])
+            return core_jit(*args, **kw)
+
+        monkeypatch.setattr(ops, "_platform", lambda x: platform)
+        monkeypatch.setattr(ops, "_core_jit", spy)
+        rng = np.random.default_rng(5)
+        table = pad_ops([TestKernelVsReference._random_table(
+            rng, 12, 3, None) for _ in range(3)])
+        got = fcfs_core(table, 3, True, 3.0, 5.0, age_bound=2.0)
+        want_stats = fcfs_core_ref(table, 3, True, 3.0, 5.0, age_bound=2.0)
+        assert seen == [want]
+        for g, w in zip(got, want_stats):
+            assert np.array_equal(g, w)
 
 
 class TestCompileCache:

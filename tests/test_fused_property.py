@@ -5,10 +5,11 @@ count, per-cell timing scalars and aging bounds) must produce
 bitwise-equal ``(fin, diestat, lane)`` between one
 :func:`repro.kernels.fcfs_core.ops.fused_core` dispatch and the
 per-cell oracle :func:`repro.kernels.fcfs_core.ref.fused_core_ref` —
-the cell-axis law.  A deterministic seeded sweep always runs; when the
-optional ``hypothesis`` dependency is installed (mirrors
-``test_batched_property.py``), the same check additionally runs on
-hypothesis-drawn shapes.
+the cell-axis law.  A deterministic seeded sweep always runs, on the
+platform's carry lowering and again forced to the TPU's ``"select"``
+lowering; when the optional ``hypothesis`` dependency is installed
+(mirrors ``test_batched_property.py``), the same check additionally
+runs on hypothesis-drawn shapes.
 """
 
 import numpy as np
@@ -22,6 +23,7 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.flashsim.simtime import on_grid
+from repro.kernels.fcfs_core import ops
 from repro.kernels.fcfs_core.ops import fused_core, pad_ops, pad_width
 from repro.kernels.fcfs_core.ref import fused_core_ref
 
@@ -85,9 +87,18 @@ _SEEDED_DRAWS = [
 ]
 
 
-@pytest.mark.parametrize("draw", _SEEDED_DRAWS,
-                         ids=[f"seed{d[0]}" for d in _SEEDED_DRAWS])
-def test_fused_kernel_matches_cell_axis_oracle_seeded(draw):
+@pytest.mark.parametrize(
+    "draw,lowering",
+    [(d, None) for d in _SEEDED_DRAWS]
+    + [(d, "select") for d in _SEEDED_DRAWS],
+    ids=[f"seed{d[0]}" for d in _SEEDED_DRAWS]
+    + [f"seed{d[0]}-select" for d in _SEEDED_DRAWS])
+def test_fused_kernel_matches_cell_axis_oracle_seeded(draw, lowering,
+                                                      monkeypatch):
+    # ``lowering`` None: the one this platform picks; else forced.
+    if lowering is not None:
+        monkeypatch.setattr(ops, "carry_lowering",
+                            lambda platform, lanes: lowering)
     _check_draw(draw)
 
 
