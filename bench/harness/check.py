@@ -1,6 +1,6 @@
 """Whether what the timed path produced is correct: every statistic of a
-sample of the window's cells against the plain reference
-(:mod:`reference.sim`), exactly.
+sample of the window's cells against the plain reference that the
+cell's configuration names (``cell.reference``), exactly.
 
 The number compared is ``mismatched_fields``: over the sampled cells,
 how many (cell, statistic) pairs differ from the reference.  Its limit
@@ -15,7 +15,10 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-from reference import sim as refsim
+# The deal of a call's requests to its base trace's arrivals belongs to
+# the traffic, not to the reference that judges it: the program's trace
+# and every reference's are dealt by this one function.
+from reference.sim import call_trace
 
 #: Each compared number's limit, as (comparison that passes, limit).
 #: ``mismatched_fields`` is an exact comparison; at least one cell must
@@ -65,10 +68,12 @@ def sample_cells(calls, seed: int) -> List[Sampled]:
     return out
 
 
-def mismatches(program_stats, reference: dict) -> Dict[str, tuple]:
-    """Statistics on which the program and the reference differ."""
+def mismatches(program_stats, reference: dict,
+               fields) -> Dict[str, tuple]:
+    """Statistics, of ``fields``, on which the program and the reference
+    differ."""
     out = {}
-    for f in refsim.STAT_FIELDS:
+    for f in fields:
         got = getattr(program_stats, f, None)
         if got != reference[f]:
             out[f] = (got, reference[f])
@@ -82,23 +87,24 @@ def compare(cell, sampled: List[Sampled], time_dtype=float):
     ``time_dtype`` other than float runs the reference in a narrower
     precision (the control) and compares that with the reference
     instead."""
+    refmod = cell.reference
     bases = {}
     diffs = []
     for c in sampled:
         if c.trace_seed not in bases:
-            bases[c.trace_seed] = refsim.generate_trace(
+            bases[c.trace_seed] = refmod.generate_trace(
                 cell.workload, cell.n_requests, c.trace_seed)
-        trace = refsim.call_trace(bases[c.trace_seed], c.seed)
-        ref = refsim.simulate(cell.drive, trace, c.condition, c.mechanism,
+        trace = call_trace(bases[c.trace_seed], c.seed)
+        ref = refmod.simulate(cell.drive, trace, c.condition, c.mechanism,
                               c.seed)
         if time_dtype is not float:
-            got = refsim.simulate(cell.drive, trace, c.condition,
+            got = refmod.simulate(cell.drive, trace, c.condition,
                                   c.mechanism, c.seed,
                                   time_dtype=time_dtype)
-            bad = {f: (got[f], ref[f]) for f in refsim.STAT_FIELDS
+            bad = {f: (got[f], ref[f]) for f in refmod.STAT_FIELDS
                    if got[f] != ref[f]}
         else:
-            bad = mismatches(c.stats, ref)
+            bad = mismatches(c.stats, ref, refmod.STAT_FIELDS)
         if bad:
             diffs.append({"call": c.call, "trace_seed": c.trace_seed,
                           "mechanism": c.mechanism,

@@ -134,9 +134,8 @@ class Program:
                              gc=GCConfig(**d["gc"]), **plain)
         w = cell.workload
         self.workload = fs.Workload(
-            w["name"], read_ratio=w["read_ratio"], iops=w["iops"],
-            burstiness=w["burstiness"], mean_pages=w["mean_pages"],
-            n_requests=cell.n_requests, span_pages=w["span_pages"])
+            w["name"], n_requests=cell.n_requests,
+            **{k: v for k, v in w.items() if k != "name"})
         self.source_type = _call_source(fs)
 
     def source(self, trace_seed: int):
@@ -183,9 +182,8 @@ class Program:
 def _call_source(fs):
     """A trace source (the program's ``TraceSource`` interface): the
     program's generator draws the base trace from the pool member's
-    seed, dealt in an order drawn from the call's seed
-    (``reference.sim.call_trace``)."""
-    from reference.sim import call_trace
+    seed, dealt in an order drawn from the call's seed by the harness's
+    one deal, ``check.call_trace``, whatever reference checks it."""
 
     class CallTrace(fs.TraceSource):
         def __init__(self, workload, trace_seed):
@@ -193,7 +191,7 @@ def _call_source(fs):
 
         def _build(self, seed):
             t = fs.generate_trace(self.workload, seed=self.trace_seed)
-            arrival, is_read, n_pages, start = call_trace(
+            arrival, is_read, n_pages, start = check.call_trace(
                 (t.arrival_us, t.is_read, t.n_pages, t.start_page), seed)
             return fs.RequestTrace(arrival, is_read, n_pages, start)
 

@@ -3,25 +3,42 @@
 A cell (an entry of ``workloads``) names a configuration, whose file
 holds the drive and the host workload, and a traffic mix, whose file
 ``bench/workloads/<traffic>.json`` holds the calls one client makes.
-Metrics are computed by readers ``bench/metrics/<metric name>.py``.
-Everything is found by name; nothing here knows a particular cell.
+Metrics are computed by readers ``bench/metrics/<metric name>.py``, and
+the configuration names the reference that checks it,
+``bench/reference/<name>.py`` (the contract is in ``reference``'s
+docstring).  Everything is found by name; nothing here knows a
+particular cell.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import os
+import re
+import types
 from typing import Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT_DIR = os.path.dirname(BENCH_DIR)
 
+# The core every configuration states; a reference may model further
+# drive and workload keys (its DRIVE_KEYS and WORKLOAD_KEYS).
 _DRIVE_KEYS = {"n_channels", "dies_per_channel", "page_kib",
                "host_overhead_us", "timing", "scheduler", "gc"}
 _TIMING_KEYS = {"tr_us", "tdma_us", "tecc_us", "tprog_us"}
 _WORKLOAD_KEYS = {"name", "read_ratio", "iops", "burstiness", "mean_pages",
                   "span_pages"}
+# The fields of the program's GCConfig (repro.flashsim.config), named
+# here so that checking a configuration imports nothing of the program.
+_GC_KEYS = {"enabled", "mode", "watermark_blocks", "op_ratio",
+            "pages_per_block", "blocks_per_die", "gc_threshold_blocks",
+            "t_erase_us", "pec_per_erase"}
+_DEFAULT_REFERENCE = "sim"
+_REFERENCE_NAME = re.compile(r"[a-z_][a-z0-9_]*")
+_REFERENCE_API = ("STAT_FIELDS", "generate_trace", "simulate",
+                  "DRIVE_KEYS", "WORKLOAD_KEYS", "accepts")
 APIS = ("simulate_batch", "simulate")
 
 
@@ -49,6 +66,7 @@ class Cell:
     name: str
     chips: int
     config: dict          # the configuration file
+    reference: types.ModuleType   # the reference the configuration names
     traffic: dict         # the traffic file
     calls: tuple          # Call shapes, cycled through by the client
     end_to_end: tuple     # metric entries of BENCHMARK.json this cell reports
@@ -96,27 +114,49 @@ def _check_keys(what: str, d: dict, allowed: set, required: set) -> None:
                         f"missing keys {sorted(missing)}")
 
 
-def validate_config(name: str, cfg: dict) -> None:
-    """A configuration file states a drive and a host workload."""
+def load_reference(name) -> types.ModuleType:
+    """The reference module ``bench/reference/<name>.py``, which keeps to
+    the contract in ``reference``'s docstring."""
+    import reference
+
+    if not isinstance(name, str) or not _REFERENCE_NAME.fullmatch(name) or \
+            not any(os.path.isfile(os.path.join(d, f"{name}.py"))
+                    for d in reference.__path__):
+        raise SpecError(f"no reference {name!r} in bench/reference/")
+    mod = importlib.import_module(f"reference.{name}")
+    missing = [a for a in _REFERENCE_API if not hasattr(mod, a)]
+    if missing:
+        raise SpecError(f"reference {name!r} lacks {missing}")
+    return mod
+
+
+def validate_config(name: str, cfg: dict) -> types.ModuleType:
+    """A configuration file states a drive and a host workload, and may
+    name the reference that checks it (``reference``, default ``sim``).
+    Returns that reference's module."""
     if cfg.get("name") != name:
         raise SpecError(f"configuration file of {name!r} names "
                         f"{cfg.get('name')!r}")
     for key in ("source", "drive", "workload", "n_requests", "guarantees"):
         if key not in cfg:
             raise SpecError(f"configuration {name!r} lacks {key!r}")
+    ref = load_reference(cfg.get("reference", _DEFAULT_REFERENCE))
     drive = cfg["drive"]
-    _check_keys(f"{name}.drive", drive, _DRIVE_KEYS, _DRIVE_KEYS)
+    _check_keys(f"{name}.drive", drive, _DRIVE_KEYS | set(ref.DRIVE_KEYS),
+                _DRIVE_KEYS)
     _check_keys(f"{name}.drive.timing", drive["timing"], _TIMING_KEYS,
                 _TIMING_KEYS)
     if set(drive["timing"]["tr_us"]) != {"lsb", "csb", "msb"}:
         raise SpecError(f"{name}: tr_us needs lsb, csb and msb")
-    if drive["scheduler"] != "fcfs" or drive["gc"] != {"enabled": False}:
-        raise SpecError(f"{name}: the reference models FIFO die queues "
-                        f"(fcfs) without garbage collection")
-    _check_keys(f"{name}.workload", cfg["workload"], _WORKLOAD_KEYS,
-                _WORKLOAD_KEYS)
+    _check_keys(f"{name}.drive.gc", drive["gc"], _GC_KEYS, set())
+    _check_keys(f"{name}.workload", cfg["workload"],
+                _WORKLOAD_KEYS | set(ref.WORKLOAD_KEYS), _WORKLOAD_KEYS)
+    reason = ref.accepts(drive, cfg["workload"])
+    if reason:
+        raise SpecError(f"{name}: {reason}")
     if int(cfg["n_requests"]) < 1:
         raise SpecError(f"{name}: n_requests must be >= 1")
+    return ref
 
 
 def parse_traffic(name: str, traffic: dict) -> tuple:
@@ -180,7 +220,7 @@ def load_cell(name: str, root: str = ROOT_DIR,
         raise SpecError(f"workload {name!r} names unknown configuration "
                         f"{entry['config']!r}")
     cfg = _load_json(os.path.join(root, conf["file"]))
-    validate_config(conf["name"], cfg)
+    ref = validate_config(conf["name"], cfg)
     tpath = os.path.join(root, "bench", "workloads",
                          f"{entry['traffic']}.json")
     traffic = _load_json(tpath)
@@ -191,5 +231,5 @@ def load_cell(name: str, root: str = ROOT_DIR,
             raise SpecError(f"metric {m['name']!r} has no reader "
                             f"bench/metrics/{m['name']}.py")
     return Cell(name=name, chips=int(entry["chips"]), config=cfg,
-                traffic=traffic,
+                reference=ref, traffic=traffic,
                 calls=calls, end_to_end=e2e, per_layer=layer)

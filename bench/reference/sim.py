@@ -55,6 +55,20 @@ STAT_FIELDS = (
     "die_sense_util",
 )
 
+#: Optional configuration keys it models: none beyond the core.
+DRIVE_KEYS = frozenset()
+WORKLOAD_KEYS = frozenset()
+
+
+def accepts(drive: dict, workload: dict):
+    """Why this reference cannot model ``drive``, or None: it models
+    FIFO die queues without garbage collection."""
+    if drive["scheduler"] != "fcfs" or drive["gc"] != {"enabled": False}:
+        return ("the reference models FIFO die queues (fcfs) without "
+                "garbage collection")
+    return None
+
+
 MECHANISMS = {
     #           pipelined, adaptive tR, SOTA start
     "baseline": (False, False, False),

@@ -6,10 +6,10 @@ Runs one cell of ``BENCHMARK.json`` on the accelerator of this machine:
 sets up (imports, characterization, one warm-up call per call shape of
 the cell's traffic), drives the run API in a closed loop for
 ``--seconds``, checks a sample of the window's cells against the plain
-reference in ``bench/reference``, and prints as its last line one JSON
-object: ``correct``, ``attempted``, ``failed``, ``metrics``, ``device``,
-with ``--trace 1`` a ``breakdown``, and last ``checks`` (each compared
-number beside its limit).  ``--trace 0`` reports the cell's end-to-end
+reference its configuration names (``bench/reference/<name>.py``), and
+prints as its last line one JSON object: ``correct``, ``attempted``,
+``failed``, ``metrics``, ``device``, with ``--trace 1`` a ``breakdown``,
+and last ``checks`` (each compared number beside its limit).  ``--trace 0`` reports the cell's end-to-end
 metrics, ``--trace 1`` its per-layer metrics from a profiler trace of
 the window's first calls.
 

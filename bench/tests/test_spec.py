@@ -8,6 +8,7 @@ import re
 
 import pytest
 
+from conftest import gc_config, gc_root
 from harness import spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -107,3 +108,51 @@ def test_traffic_validation():
     for seeds in (None, [], [-1], [3, 3], [1.5]):
         with pytest.raises(spec.SpecError, match="trace_seeds"):
             spec.parse_traffic("t", dict(ok, trace_seeds=seeds))
+
+
+def test_named_reference_loads(stub_reference, tmp_path):
+    root, b = gc_root(tmp_path, gc_config(stub_reference))
+    cell = spec.load_cell("gc.grid", root=root, bench=b)
+    assert cell.reference.__name__ == "reference.prio_gc"
+    assert cell.drive["scheduler"] == "host_prio_aged:8"
+    assert cell.drive["gc"]["enabled"] is True
+    assert spec.load_cell("websearch.grid").reference.__name__ == \
+        "reference.sim"
+
+
+@pytest.mark.parametrize("reference", [None, "sim"])
+def test_gc_drive_refused_by_sim(reference, tmp_path):
+    root, b = gc_root(tmp_path, gc_config(reference))
+    with pytest.raises(spec.SpecError, match="FIFO"):
+        spec.load_cell("gc.grid", root=root, bench=b)
+
+
+@pytest.mark.parametrize("name", [
+    "no_such_reference", "../reference/sim", "reference/sim", "..", "./sim",
+    "/sim", "Sim", "sim\n", "", 3, "nand", "__init__"])
+def test_reference_name_refused(name, stub_reference):
+    with pytest.raises(spec.SpecError, match="no reference|lacks"):
+        spec.validate_config("gc-stub", gc_config(name))
+
+
+@pytest.mark.parametrize("where,key", [
+    ("drive", "bogus"), ("workload", "bogus"), ("gc", "bogus"),
+    ("drive", "ncq_depth")])
+def test_undeclared_keys_refused(where, key, stub_reference):
+    cfg = gc_config(stub_reference, ecc_engines_per_channel=2)
+    assert spec.validate_config("gc-stub", cfg).__name__ == \
+        "reference.prio_gc"
+    part = cfg["drive"]["gc"] if where == "gc" else cfg[where]
+    part[key] = 1
+    with pytest.raises(spec.SpecError, match="unknown keys"):
+        spec.validate_config("gc-stub", cfg)
+
+
+def test_gc_keys_are_gcconfig_fields():
+    """spec names GCConfig's fields without importing the program; the
+    two lists stay one."""
+    import dataclasses
+
+    from repro.flashsim.config import GCConfig
+
+    assert spec._GC_KEYS == {f.name for f in dataclasses.fields(GCConfig)}
